@@ -54,7 +54,7 @@ def measure_variant(arch, shape_name, layer_points, overrides, fsdp, mesh):
         ov = dict(overrides, n_layers=n)
         fn, args, in_sh, out_sh, cfg, pspecs, shape = build_cell(
             arch, shape_name, mesh, unroll=True, overrides=ov, fsdp=fsdp)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh,
                                out_shardings=out_sh).lower(*args).compile()
         ca = compiled.cost_analysis()

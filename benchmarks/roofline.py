@@ -97,7 +97,7 @@ def compute(archs=None, shapes=None):
                     fn, args, in_sh, out_sh, cfg, pspecs, shape = build_cell(
                         arch, shape_name, mesh, unroll=True,
                         overrides={"n_layers": n})
-                    with mesh:
+                    with jax.set_mesh(mesh):
                         compiled = jax.jit(fn, in_shardings=in_sh,
                                            out_shardings=out_sh).lower(*args).compile()
                     ca = compiled.cost_analysis()

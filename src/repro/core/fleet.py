@@ -298,9 +298,15 @@ def fleet_episode(cfg: FCPOConfig, fleet: Fleet, rates: jnp.ndarray,
     HEALTH_METRIC_KEYS``); the fleet must carry matching health state
     (``fleet_init(..., health=...)``). None (default) stages the exact
     pre-health program."""
+    # Under a mesh the agent axis is named, so a Pallas kernel the episode
+    # runs per agent (kernels.ops shard_maps it) splits over the devices
+    # that hold those agents.
+    mesh = shd.ambient_mesh()
+    spmd = None if mesh is None else shd.agent_axes(rates.shape[0], mesh)
     astate, rollouts, metrics = jax.vmap(
         lambda ep, st, r, m: crl_episode(cfg, ep, st, r, m, learn, backend,
-                                         health=health is not None)
+                                         health=health is not None),
+        spmd_axis_name=spmd,
     )(fleet.env_params, fleet.astate, rates, fleet.masks)
     hstate = fleet.health
     if health is not None:
@@ -991,6 +997,10 @@ def _prep_scan_args(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
             faults, guards, stream, trace, health)
 
 
+def _mesh_context(mesh):
+    return jax.set_mesh(mesh) if mesh is not None else nullcontext()
+
+
 def lower_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                      learn: bool = True, federated: bool = True,
                      straggler_prob: float = 0.0, seed: int = 0,
@@ -1011,10 +1021,10 @@ def lower_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                            transport, faults, guards, episode_offset,
                            total_episodes, sink_id=0, stream=False,
                            tracer=None, health=health)
-    # trace under the mesh's resource env so the in-graph sharding hints
+    # trace under the mesh so the in-graph sharding hints
     # (sharding.ambient_mesh) resolve — the analyzed program is the meshed
     # program train_fleet_scan would run
-    with (mesh if mesh is not None else nullcontext()):
+    with _mesh_context(mesh):
         return _scan_fn(bool(donate)).lower(*args)
 
 
@@ -1106,13 +1116,12 @@ def train_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                            total_episodes, sink_id=sid, stream=stream,
                            tracer=tracer, health=health)
     try:
-        # entering the mesh's resource env activates the in-graph sharding
+        # setting the mesh as the context mesh activates the in-graph sharding
         # hints (agents over (pod, data), pods over the FL hierarchy): the
         # Alg. 1 segment-sums and the pod merge lower to real collectives.
         # Without a mesh the hints are no-ops and the traced program is the
         # exact single-device one.
-        with obs_trace.activate(tracer), \
-                (mesh if mesh is not None else nullcontext()):
+        with obs_trace.activate(tracer), _mesh_context(mesh):
             fleet, history = _scan_fn(bool(donate))(*args)
             history = jax.device_get(history)
     finally:

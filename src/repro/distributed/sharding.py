@@ -260,22 +260,17 @@ def agent_batch_spec(shape, mesh, agent_axis: int = 1) -> P:
 
 
 def ambient_mesh():
-    """The mesh in context at trace time: abstract (jax.set_mesh) or the
-    legacy physical resource env (``with mesh:``). None when absent."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m.shape:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:  # noqa: BLE001
-        pass
-    return None
+    """The mesh in context at trace time (``jax.set_mesh``); None when
+    there is none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
+def agent_axes(n_agents: int, mesh):
+    """The mesh axes a fleet of ``n_agents`` is sharded over (the leading
+    entry of ``agent_spec``), or None when it is replicated."""
+    spec = agent_spec((n_agents,), mesh)
+    return spec[0] if len(spec) else None
 
 
 def shard_hint(x, *dim_prefs, priority=None):

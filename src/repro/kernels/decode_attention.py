@@ -100,7 +100,7 @@ def decode_attention_bhd(q, k_cache, v_cache, kv_len, *, bk=512,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(kv_len, jnp.int32).reshape(1), qg, k_cache, v_cache)

@@ -12,13 +12,13 @@ fleet of A agents is one kernel call over grid (A,).
 The per-coordinate math is imported from ``repro.kernels.ref``
 (``delta_codec_step`` — the same function the jnp oracle ``delta_codec_ref``
 calls), so kernel and oracle agree bit-for-bit (equivalence-tested in
-tests/test_fl.py, including under ``vmap``). On this CPU container the
-kernel executes with ``interpret=True`` (same body, XLA-CPU execution); on
-TPU the float32/int8 bodies (element-wise + reductions) compile to Mosaic,
-while topk's sort-based exact-k selection is currently only exercised in
-interpret mode — a Mosaic-native selection (threshold refinement instead of
-a full sort) is the known follow-up before enabling ``use_pallas`` topk on
-real TPU hardware.
+tests/test_fl.py, including under ``vmap``). On CPU the kernel executes
+with ``interpret=True`` (same body, XLA-CPU execution); on TPU all three
+codecs compile to Mosaic (tests/test_tpu_compile.py) — topk's exact-k
+selection is a bitwise binary search over the float bit patterns
+(``ref.topk_mask``), not a sort, which Mosaic lacks. Each agent's
+(L,) vector travels as a (1, 1, L) block of an (A, 1, L) view: a block's
+last two dims must equal the array's (or tile (8, 128)).
 """
 from __future__ import annotations
 
@@ -32,10 +32,10 @@ from repro.kernels import ref as kref
 
 
 def _codec_kernel(delta_ref, res_ref, o_dec, o_res, *, codec, k):
-    xf = delta_ref[0] + res_ref[0]
+    xf = delta_ref[0, 0] + res_ref[0, 0]
     dec, new_res = kref.delta_codec_step(xf, codec=codec, k=k)
-    o_dec[0] = dec
-    o_res[0] = new_res
+    o_dec[0, 0] = dec
+    o_res[0, 0] = new_res
 
 
 def delta_codec(delta, residual, *, codec: str, k: int = 1, interpret=False):
@@ -55,19 +55,16 @@ def delta_codec(delta, residual, *, codec: str, k: int = 1, interpret=False):
     f32 = jnp.float32
 
     kernel = functools.partial(_codec_kernel, codec=codec, k=k)
-    spec = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda a_: (a_,) + (0,) * len(shape))
+    spec = pl.BlockSpec((1, 1, l), lambda a_: (a_, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(a,),
-        in_specs=[spec(l), spec(l)],
-        out_specs=[spec(l), spec(l)],
-        out_shape=[
-            jax.ShapeDtypeStruct((a, l), f32),
-            jax.ShapeDtypeStruct((a, l), f32),
-        ],
+        in_specs=[spec, spec],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((a, 1, l), f32)] * 2,
         interpret=interpret,
-    )(delta.astype(f32), residual.astype(f32))
+    )(delta.astype(f32).reshape(a, 1, l), residual.astype(f32).reshape(a, 1, l))
+    out = [x.reshape(a, l) for x in out]
 
     if unbatched:
         out = jax.tree.map(lambda x: x[0], out)

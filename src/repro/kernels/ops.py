@@ -3,8 +3,9 @@
 Dispatch is backend-aware: on CPU the kernels execute with
 ``interpret=True`` (Pallas interpreter — same kernel body, Python/XLA-CPU
 execution); on any accelerator backend the same call sites compile (TPU ->
-Mosaic, GPU -> Triton). ``REPRO_PALLAS_INTERPRET=1/0`` force-overrides in
-either direction. The model code defaults to the jnp reference path under dry-run
+Mosaic, GPU -> Triton). To see what Mosaic makes of a kernel without a chip,
+compile it ahead of time for a described TPU (tests/test_tpu_compile.py).
+The model code defaults to the jnp reference path under dry-run
 (identical math — see DESIGN.md §6) and switches to these via
 ``use_pallas=True``.
 
@@ -22,10 +23,11 @@ into the untraced cache.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
+from jax.sharding import PartitionSpec as P
 
+from repro.distributed import sharding as shd
 from repro.kernels import decode_attention as _dec
 from repro.kernels import delta_codec as _codec
 from repro.kernels import diversity as _div
@@ -38,12 +40,7 @@ from repro.obs import trace as obs_trace
 def _interpret_default() -> bool:
     """Backend-aware kernel dispatch: interpret on CPU (no Pallas lowering
     there), compiled Pallas on every accelerator backend (TPU -> Mosaic,
-    GPU -> Triton). ``REPRO_PALLAS_INTERPRET=1/0`` force-overrides either
-    way (e.g. interpret-on-TPU for kernel debugging, or compiled-on-CPU to
-    reproduce a lowering error report)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    GPU -> Triton)."""
     return jax.default_backend() == "cpu"
 
 
@@ -110,11 +107,29 @@ _DELTA_CODEC = _twins("delta_codec", _delta_codec_impl, ("codec", "k"))
 _QUEUE_ADVANCE = _twins("queue_advance", _queue_advance_impl)
 
 
-def _dispatch(twins, args, kw):
+def _dispatch(twins, args, kw, agent_batched=None):
+    """Call the untraced or the traced twin. ``agent_batched`` marks a fleet
+    kernel: True when ``args`` lead with the agent dim, False when they are
+    one agent's (the call then sits under the fleet's ``vmap``). Under an
+    ambient mesh such a kernel runs in ``shard_map``, each device on its
+    own agents: Mosaic kernels cannot be partitioned automatically, and
+    agents are independent. Unbatched operands are replicated there; the
+    fleet's ``vmap(spmd_axis_name=...)`` shards the agent dim it adds."""
     tid = obs_trace.kernel_trace_tid()
     if tid is None:
-        return twins[0](*args, **kw)
-    return twins[1](tid, *args, **kw)
+        call = lambda *a: twins[0](*a, **kw)
+    else:
+        args = (tid,) + tuple(args)
+        call = lambda t, *a: twins[1](t, *a, **kw)
+    mesh = None if agent_batched is None else shd.ambient_mesh()
+    if mesh is None:
+        return call(*args)
+    agent = P(shd.agent_axes(args[-1].shape[0], mesh)) if agent_batched \
+        else P()
+    in_specs = tuple(P() if tid is not None and i == 0 else agent
+                     for i in range(len(args)))
+    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                         out_specs=agent, check_vma=False)(*args)
 
 
 def flash_attention(q, k, v, *, causal=True, bq=128, bk=128):
@@ -139,7 +154,8 @@ def diversity_insert(states, probs, score, filled, s_sum, s_outer, p_sum,
     return _dispatch(_DIVERSITY,
                      (states, probs, score, filled, s_sum, s_outer, p_sum,
                       n_filled, cand_states, cand_probs),
-                     dict(alpha=alpha, beta=beta, ridge=ridge))
+                     dict(alpha=alpha, beta=beta, ridge=ridge),
+                     agent_batched=states.ndim == 3)
 
 
 def delta_codec(delta, residual, *, codec, k=1):
@@ -148,7 +164,7 @@ def delta_codec(delta, residual, *, codec, k=1):
     lossy on-wire round trip plus the carried residuals. Oracle:
     ``repro.kernels.ref.delta_codec_ref``."""
     return _dispatch(_DELTA_CODEC, (delta, residual),
-                     dict(codec=codec, k=k))
+                     dict(codec=codec, k=k), agent_batched=delta.ndim == 2)
 
 
 def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps):
@@ -158,7 +174,7 @@ def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps):
     Oracle: ``repro.kernels.ref.queue_advance_ref``."""
     return _dispatch(_QUEUE_ADVANCE,
                      (arrive, counters, credits, lat_sum, hist, arrivals,
-                      caps), {})
+                      caps), {}, agent_batched=arrive.ndim == 2)
 
 
 # name -> untraced jit wrapper — the profiler (repro.obs.profile) uses

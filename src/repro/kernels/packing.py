@@ -34,20 +34,23 @@ def _pack_kernel(idx_ref, tok_ref, o_ref):
 def pack(tokens, indices, *, interpret=False):
     """tokens: (T, D); indices: (N,) int32, negative = padding.
 
-    Returns (N, D) with out[i] = tokens[indices[i]] (0 for padding)."""
+    Returns (N, D) with out[i] = tokens[indices[i]] (0 for padding). Rows
+    travel as (1, 1, D) blocks of a (T, 1, D) view, whose last two dims
+    equal the array's, as Mosaic needs."""
     t, d = tokens.shape
     n = indices.shape[0]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _pack_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, d),
-                             lambda i, idx_ref: (jnp.maximum(idx_ref[i], 0), 0)),
+                pl.BlockSpec((1, 1, d), lambda i, idx_ref: (
+                    jnp.maximum(idx_ref[i], 0), 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+            out_specs=pl.BlockSpec((1, 1, d), lambda i, idx_ref: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n, d), tokens.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), tokens.dtype),
         interpret=interpret,
-    )(indices.astype(jnp.int32), tokens)
+    )(indices.astype(jnp.int32), tokens.reshape(t, 1, d))
+    return out.reshape(n, d)

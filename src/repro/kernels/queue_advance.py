@@ -11,9 +11,10 @@ trips. A fleet of A agents is one kernel call over grid (A,).
 The per-tick math is imported from ``repro.kernels.ref.sim_microtick`` — the
 same function the jnp oracle (``queue_advance_ref``) scans — so kernel and
 oracle agree bit-for-bit (equivalence-tested in tests/test_sim.py, including
-under ``vmap``). On this CPU container the kernel executes with
-``interpret=True`` (same body, XLA-CPU execution); on TPU the same call site
-compiles to Mosaic.
+under ``vmap``). On CPU the kernel executes with ``interpret=True`` (same
+body, XLA-CPU execution); on TPU the same call site compiles to Mosaic
+(tests/test_tpu_compile.py), each per-agent leaf travelling as a
+(1, 1, n) block of an (A, 1, n) view.
 """
 from __future__ import annotations
 
@@ -30,21 +31,25 @@ def _queue_kernel(arrive_ref, counters_ref, credits_ref, latsum_ref,
                   hist_ref, arrivals_ref, caps_ref,
                   o_arrive, o_counters, o_credits, o_latsum, o_hist,
                   *, k_ticks):
-    caps = caps_ref[0]
+    caps = caps_ref[0, 0]
+    arrivals = arrivals_ref[0, 0]
+    tick_ids = kref._iota(k_ticks)
 
-    def tick(t, carry):
-        n_arr = arrivals_ref[0, pl.ds(t, 1)][0]
-        return kref.sim_microtick(*carry, n_arr, caps)
+    # The loop carries each leaf as a (1, n) row and the tick works on the
+    # (n,) vector: Mosaic cannot carry a 1-D vector through a loop whose
+    # body rewrites it.
+    def tick(t, rows):
+        # a masked sum, not arrivals[t]: Mosaic cannot slice lanes at a
+        # dynamic offset
+        n_arr = jnp.sum(jnp.where(tick_ids == t, arrivals, 0))
+        out = kref.sim_microtick(*(r[0] for r in rows), n_arr, caps)
+        return tuple(x[None] for x in out)
 
-    init = (arrive_ref[0], counters_ref[0], credits_ref[0], latsum_ref[0],
-            hist_ref[0])
-    arrive, counters, credits, lat_sum, hist = jax.lax.fori_loop(
-        0, k_ticks, tick, init)
-    o_arrive[0] = arrive
-    o_counters[0] = counters
-    o_credits[0] = credits
-    o_latsum[0] = lat_sum
-    o_hist[0] = hist
+    refs = (arrive_ref, counters_ref, credits_ref, latsum_ref, hist_ref)
+    outs = (o_arrive, o_counters, o_credits, o_latsum, o_hist)
+    rows = jax.lax.fori_loop(0, k_ticks, tick, tuple(r[0] for r in refs))
+    for o, row in zip(outs, rows):
+        o[0] = row
 
 
 def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps,
@@ -70,26 +75,25 @@ def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps,
     f32, i32 = jnp.float32, jnp.int32
 
     kernel = functools.partial(_queue_kernel, k_ticks=k_ticks)
-    spec = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda a_: (a_,) + (0,) * len(shape))
+    # every per-agent leaf is viewed as (A, 1, n) and blocked (1, 1, n):
+    # the block's last two dims then equal the array's, which Mosaic needs
+    widths = (ring, kref.SIM_NCOUNTERS, 2, 1, hist_n, k_ticks,
+              kref.SIM_NCAPS)
+    dtypes = (i32, i32, f32, f32, i32, i32, f32)
+    specs = [pl.BlockSpec((1, 1, w), lambda a_: (a_, 0, 0)) for w in widths]
     out = pl.pallas_call(
         kernel,
         grid=(a,),
-        in_specs=[spec(ring), spec(kref.SIM_NCOUNTERS), spec(2), spec(),
-                  spec(hist_n), spec(k_ticks), spec(kref.SIM_NCAPS)],
-        out_specs=[spec(ring), spec(kref.SIM_NCOUNTERS), spec(2), spec(),
-                   spec(hist_n)],
-        out_shape=[
-            jax.ShapeDtypeStruct((a, ring), i32),
-            jax.ShapeDtypeStruct((a, kref.SIM_NCOUNTERS), i32),
-            jax.ShapeDtypeStruct((a, 2), f32),
-            jax.ShapeDtypeStruct((a,), f32),
-            jax.ShapeDtypeStruct((a, hist_n), i32),
-        ],
+        in_specs=specs,
+        out_specs=specs[:5],
+        out_shape=[jax.ShapeDtypeStruct((a, 1, w), d)
+                   for w, d in zip(widths[:5], dtypes[:5])],
         interpret=interpret,
-    )(arrive.astype(i32), counters.astype(i32), credits.astype(f32),
-      lat_sum.astype(f32), hist.astype(i32), arrivals.astype(i32),
-      caps.astype(f32))
+    )(*(x.astype(d).reshape(a, 1, w) for x, d, w in zip(
+        (arrive, counters, credits, lat_sum, hist, arrivals, caps),
+        dtypes, widths)))
+    out = [x.reshape(x.shape[0], -1) for x in out]
+    out[3] = out[3][:, 0]
 
     if unbatched:
         out = jax.tree.map(lambda x: x[0], out)

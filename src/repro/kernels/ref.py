@@ -63,23 +63,52 @@ def pack_ref(tokens, indices):
 # fixed chain of vector ops that is legal inside jit, vmap, lax.scan, and a
 # Pallas kernel alike (``jnp.linalg`` custom calls are none of those).
 
-def chol_small(cov, eps=1e-12):
-    """Cholesky factor of a small static-D SPD matrix, unrolled over D."""
+def chol_small(cov, eps=1e-12, *, masked=False):
+    """Cholesky factor of a small static-D SPD matrix, unrolled over D.
+
+    ``masked`` writes each entry by a select over the whole tile instead of
+    an indexed update, for Mosaic, which has no scatter; the arithmetic of
+    every entry is the same."""
     d = cov.shape[0]
     l = jnp.zeros_like(cov)
+    if masked:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
     for j in range(d):
         acc = jnp.sum(l[j, :j] * l[j, :j]) if j else 0.0
         ljj = jnp.sqrt(jnp.maximum(cov[j, j] - acc, eps))
-        l = l.at[j, j].set(ljj)
+        if not masked:
+            l = l.at[j, j].set(ljj)
+            if j + 1 < d:
+                dots = (jnp.sum(l[j + 1:, :j] * l[j, :j][None, :], -1)
+                        if j else 0.0)
+                l = l.at[j + 1:, j].set((cov[j + 1:, j] - dots) / ljj)
+            continue
+        l = jnp.where((rows == j) & (cols == j), ljj, l)
         if j + 1 < d:
-            dots = jnp.sum(l[j + 1:, :j] * l[j, :j][None, :], -1) if j else 0.0
-            l = l.at[j + 1:, j].set((cov[j + 1:, j] - dots) / ljj)
+            dots = jnp.sum(l[:, :j] * l[j, :j][None, :], -1) if j else 0.0
+            col = (cov[:, j] - dots) / ljj
+            l = jnp.where((rows > j) & (cols == j), col[:, None], l)
     return l
 
 
-def tri_solve_small(l, b):
-    """Solve L y = b (L lower-triangular) by unrolled forward substitution."""
+def tri_solve_small(l, b, *, masked=False):
+    """Solve L y = b (L lower-triangular) by unrolled forward substitution.
+
+    ``masked`` (for Mosaic, as in ``chol_small``) keeps y as a (1, D) row
+    and reads L through masked tile sums instead of row slices; the sums
+    then add in another order, so it agrees with the indexed path to
+    float32 roundoff, not bit for bit."""
     d = l.shape[0]
+    if masked:
+        rows = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+        y = jnp.zeros((1, d), b.dtype)
+        for i in range(d):
+            acc = jnp.sum(jnp.where((rows == i) & (cols < i), l * y, 0.0))
+            lii = jnp.sum(jnp.where((rows == i) & (cols == i), l, 0.0))
+            y = jnp.where(cols[:1] == i, (b[i] - acc) / lii, y)
+        return y[0]
     y = jnp.zeros_like(b)
     for i in range(d):
         acc = jnp.sum(l[i, :i] * y[:i]) if i else 0.0
@@ -89,20 +118,22 @@ def tri_solve_small(l, b):
 
 def diversity_score_from_moments(state, probs, s_sum, s_outer, p_sum,
                                  n_filled, *, alpha, beta, ridge=0.1,
-                                 eps=1e-8):
+                                 eps=1e-8, masked=False):
     """Eq. 6 score of one candidate from running sufficient statistics only.
 
     Mahalanobis: cov = E[ssᵀ] − μμᵀ + ridge·I from (s_sum, s_outer), then
     d_M² = ‖L⁻¹(s−μ)‖² with L the Cholesky factor — O(D²) and never touches
     the N stored slots. KL uses the running probs sum the same way.
     Mathematically identical to the recompute-everything oracle
-    (``repro.core.buffer.diversity``)."""
+    (``repro.core.buffer.diversity``). ``masked``: the Mosaic-legal form of
+    the small solves (``chol_small``), for the Pallas kernel body."""
     dim = state.shape[-1]
     n = jnp.maximum(n_filled.astype(jnp.float32), 1.0)
     mu = s_sum / n
     cov = (s_outer / n - jnp.outer(mu, mu)
            + ridge * jnp.eye(dim, dtype=s_sum.dtype))
-    y = tri_solve_small(chol_small(cov), state - mu)
+    y = tri_solve_small(chol_small(cov, masked=masked), state - mu,
+                        masked=masked)
     d_m = jnp.sqrt(jnp.maximum(jnp.sum(y * y), 0.0))
     mean_p = jnp.where(n_filled > 0, p_sum / n, probs)
     pc = jnp.clip(probs, eps, 1.0)
@@ -198,18 +229,34 @@ def int8_roundtrip(xf):
 
 
 def topk_mask(mag, k: int):
-    """(n,) bool mask selecting EXACTLY the k largest-magnitude entries,
-    ties broken by lowest index. Sort + cumsum only — no argsort scatter —
-    so the same code runs inside the Pallas kernel body."""
+    """(n,) bool mask selecting EXACTLY the k largest entries of the
+    non-negative ``mag``, ties broken by lowest index.
+
+    No sort (Mosaic has none): for non-negative float32 the int32 bit
+    pattern orders like the value, so the k-th largest value is the largest
+    pattern t with count(bits >= t) >= k, built one bit at a time (31 count
+    passes); the ties at t that still fit the budget are the lowest-index
+    ones, found the same way over the index (log2 n passes). Compares and
+    sums only, all in the integer domain, so the same code runs inside the
+    Pallas kernel body and selects the same set on every backend."""
     n = mag.shape[0]
     if k >= n:
         return jnp.ones((n,), bool)
-    thresh = jnp.sort(mag)[n - k]                 # k-th largest value
-    above = mag > thresh
-    n_above = jnp.sum(above.astype(jnp.int32))
-    eq = mag == thresh
-    take_eq = eq & (jnp.cumsum(eq.astype(jnp.int32)) <= k - n_above)
-    return above | take_eq
+    bits = jax.lax.bitcast_convert_type(mag.astype(jnp.float32), jnp.int32)
+    count = lambda m: jnp.sum(m.astype(jnp.int32))
+    t = jnp.int32(0)
+    for b in range(30, -1, -1):
+        cand = t | (1 << b)
+        t = jnp.where(count(bits >= cand) >= k, cand, t)
+    above = bits > t
+    eq = bits == t
+    need = k - count(above)               # >= 1 ties at t still selected
+    idx = _iota(n)
+    j = jnp.int32(0)                      # largest j with count(eq & idx<j) < need
+    for b in range(max(n - 1, 1).bit_length() - 1, -1, -1):
+        cand = j | (1 << b)
+        j = jnp.where(count(eq & (idx < cand)) < need, cand, j)
+    return above | (eq & (idx <= j))
 
 
 def delta_codec_step(xf, *, codec: str, k: int = 1):

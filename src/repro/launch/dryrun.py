@@ -202,7 +202,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     try:
         fn, args, in_sh, out_sh, cfg, params_specs, shape = build_cell(
             arch, shape_name, mesh, unroll=unroll, overrides=overrides)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0

@@ -23,13 +23,14 @@ from repro.configs.base import get_config
 from repro.configs.fcpo import FCPOConfig
 from repro.core.fleet import fleet_episode, fleet_init, fl_round
 from repro.data.workload import fleet_traces
+from repro.launch import compile_cache
 from repro.models.registry import get_model
 from repro.serving.engine import ServingEngine
 
 
 def calibrate_env_from_engine(engine: ServingEngine, cfg_f: FCPOConfig,
                               seq: int = 32):
-    """Measure the engine's real (t0, t1) batching curve on this host and
+    """Measure the engine's real (t0, t1) batching curve on this device and
     return EnvParams matching it — so the MDP the agents learn on IS this
     data plane's latency surface."""
     from repro.core.env import EnvParams
@@ -62,6 +63,7 @@ def main(argv=None):
     ap.add_argument("--slo-ms", type=float, default=250.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -82,6 +84,7 @@ def main(argv=None):
 
     traces = fleet_traces(jax.random.PRNGKey(1), args.replicas,
                           args.episodes * cfg_f.n_steps)
+    served, rewards = [], []
     for e in range(args.episodes):
         rates = traces[:, e * cfg_f.n_steps:(e + 1) * cfg_f.n_steps]
         fleet, rollouts, metrics = fleet_episode(cfg_f, fleet, rates)
@@ -93,11 +96,15 @@ def main(argv=None):
         bs = min(bs, max(engine.batch_buckets))
         tokens = jnp.zeros((bs, 16), jnp.int32)
         out = engine.generate(tokens, steps=2)
+        served.append(out)
+        rewards.append(float(metrics["reward"].mean()))
         print(f"ep {e + 1:3d} reward {float(metrics['reward'].mean()):+.3f} "
               f"eff_thr {float(metrics['effective_throughput'].mean()):6.1f} "
               f"lat {float(metrics['latency'].mean()) * 1e3:6.1f}ms "
               f"| served real batch bs={bs} -> {out.shape}", flush=True)
     print("done")
+    return dict(engine=engine, fleet=fleet, env_params=env_params,
+                served=served, rewards=rewards)
 
 
 if __name__ == "__main__":

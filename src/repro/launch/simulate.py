@@ -25,6 +25,7 @@ from repro.configs.fcpo import FCPOConfig
 from repro.core.backends import BACKENDS, get_backend
 from repro.core.fleet import fleet_init, train_fleet
 from repro.data.workload import fleet_traces
+from repro.launch import compile_cache
 from repro.sim import SCENARIOS, SimParams, make_scenario, simulate_fleet
 
 
@@ -66,6 +67,7 @@ def main(argv=None):
                          "--attribution")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.intervals < 1:
         ap.error("--intervals must be >= 1")
     if args.ring <= 0 or args.ring & (args.ring - 1):

@@ -99,13 +99,14 @@ def main(argv=None):
 def _wrap_with_compression(model, opt_cfg, args):
     """DP train step with int8 error-feedback gradient all-reduce inside
     shard_map (beyond-paper distributed-optimization option)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
 
     from repro.training.optimizer import adamw_update
     from repro.training.train_step import make_loss_fn
 
-    mesh = jax.make_mesh((jax.device_count(),), ("dp",))
+    mesh = make_mesh((jax.device_count(),), ("dp",))
     loss_fn = make_loss_fn(model, remat=not args.no_remat)
 
     def step(state, batch):
@@ -119,7 +120,7 @@ def _wrap_with_compression(model, opt_cfg, args):
             return ({"params": new_params, "opt": new_opt, "ef": new_res},
                     {"loss": loss, **extras, **om})
 
-        inner = shard_map(
+        inner = jax.shard_map(
             local, mesh=mesh,
             in_specs=({"params": P(), "opt": P(), "ef": P()},
                       jax.tree.map(lambda _: P("dp"), batch), P()),

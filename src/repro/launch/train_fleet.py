@@ -61,6 +61,7 @@ from repro.fl import CODECS, TransportConfig
 from repro.health import HealthConfig
 from repro.health.alerts import AlertEngine
 from repro.core.dtypes import POLICIES
+from repro.launch import compile_cache
 from repro.launch.mesh import (make_debug_mesh, make_fleet_mesh,
                                make_production_mesh)
 from repro.resilience import BYZANTINE_MODES, FaultConfig, GuardConfig
@@ -69,7 +70,7 @@ from repro.sim import SCENARIOS, SimParams, make_scenario
 from repro.training import checkpoint as ckpt_mod
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--agents", type=int, default=8)
     ap.add_argument("--pods", type=int, default=2)
@@ -280,6 +281,13 @@ def main(argv=None):
     if args.health_bins != 16 and not args.health:
         ap.error("--health-bins only affects the observatory; add --health")
 
+    return args
+
+
+def build(args):
+    """The run ``args`` describe: (cfg, fleet, traces, mesh, kw) with ``kw``
+    the keywords ``train_fleet_scan`` / ``lower_fleet_scan`` take besides
+    the metrics sink and the tracer."""
     cfg = FCPOConfig() if args.fl_every is None else \
         FCPOConfig(fl_every=args.fl_every)
     faults = FaultConfig(
@@ -334,6 +342,15 @@ def main(argv=None):
               env_backend=backend, transport=transport,
               faults=faults if faults.active else None, guards=guards,
               health=health)
+    return cfg, fleet, traces, mesh, kw
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    cfg, fleet, traces, mesh, kw = build(args)
+    health, faults, guards = kw["health"], kw["faults"], kw["guards"]
+    backend = kw["env_backend"]
     # detect the auto-resume BEFORE opening the metrics sink: a resumed run
     # must append to the metrics file, not truncate the pre-kill episodes
     resume_from = (ckpt_mod.latest_step(args.ckpt_dir) or 0) \
@@ -469,7 +486,7 @@ def main(argv=None):
               f"susp last {hist['health_susp'][-1]:.3f}"
               + (f"; {engine.n_alerts} alerts -> {args.alerts_out}"
                  if engine is not None else ""))
-    if faults.active:
+    if faults is not None:
         print(f"\nchaos: crash_prob={faults.crash_prob}, "
               f"byzantine={faults.byzantine_frac} "
               f"({faults.byzantine_mode} x{faults.byzantine_scale}), "

@@ -196,3 +196,30 @@ class TestMeshedTraining:
             transport=TransportConfig(codec="int8"))
         assert np.isfinite(np.asarray(hist["reward"])).all()
         assert jax.tree.leaves(out.astate.opt["m"])[0].dtype == jnp.bfloat16
+
+    def test_meshed_kernels_match_single_device(self):
+        """The fused twin and codec kernels on the mesh: Mosaic kernels
+        cannot be partitioned by SPMD, so the ops dispatch runs them in
+        shard_map over the agent axes. Same numbers as one device."""
+        from repro.core.backends import get_backend
+        from repro.fl import TransportConfig
+        from repro.sim import SimParams
+        n, eps = 16, 4
+        backend = get_backend("twin", sim_params=SimParams(ring=64),
+                              use_pallas=True)
+        traces = fleet_traces(jax.random.PRNGKey(1), n, eps * CFG.n_steps)
+        kw = dict(env_backend=backend, seed=3,
+                  transport=TransportConfig(codec="int8", use_pallas=True))
+        with pytest.warns(UserWarning, match="clamps queue_cap"):
+            f0 = fleet_init(CFG, n, KEY, n_pods=2, env_backend=backend)
+            mesh = make_fleet_mesh(8, 2)
+            f1 = fleet_init(CFG, n, KEY, n_pods=2, env_backend=backend,
+                            mesh=mesh)
+        _, sh = train_fleet_scan(CFG, f0, traces, **kw)
+        mf, mh = train_fleet_scan(CFG, f1, traces, mesh=mesh, **kw)
+        for k in sh:
+            np.testing.assert_allclose(np.asarray(sh[k], dtype=np.float32),
+                                       np.asarray(mh[k], dtype=np.float32),
+                                       rtol=1e-5, atol=1e-5, err_msg=k)
+        per = fleet_device_bytes(mf)
+        assert len(per) == 8

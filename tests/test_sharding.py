@@ -12,8 +12,8 @@ from repro.distributed import sharding as shd
 from repro.models.registry import get_config, get_model, input_specs
 from repro.configs.base import SHAPES
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestGreedySpec:

@@ -139,14 +139,13 @@ def test_compress_psum_error_feedback_single_device():
     carries the quantization error (bias correction over steps)."""
     from jax.sharding import Mesh
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("dp",))
     grads = {"w": jax.random.normal(jax.random.PRNGKey(1), (64,))}
     res = ef_init(grads)
 
-    f = shard_map(partial(compress_psum, axis_name="dp"),
+    f = jax.shard_map(partial(compress_psum, axis_name="dp"),
                   mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     mean, new_res = f(grads, res)
     np.testing.assert_allclose(np.asarray(mean["w"] + new_res["w"]),
