@@ -1,0 +1,9 @@
+"""Device: the share of the traced window in which no operation ran on the
+device, averaged over the chips."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr.window_s or not tr.busy_s:
+        return None
+    return 100.0 * (1.0 - tr.busy_s / tr.window_s)
