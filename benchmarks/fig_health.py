@@ -19,7 +19,7 @@ Four measurements over the health observatory (``repro.health``):
     slots among that round's selected clients — at least
     ``PRECISION_MIN``.
   * ``overhead`` — health-on vs health-off wall time on representative
-    episode lengths (same ``_min_wall_us`` estimator as fig_profile).
+    episode lengths.
     Gates: overhead within ``OVERHEAD_MAX``, and the health-on cadence
     stays ONE jitted scan (no per-episode host entries, same-shaped rerun
     hits the compiled executable).
@@ -221,9 +221,9 @@ def run_attribution(n_agents=8, n_eps=16, seed=0):
 def run_overhead(n_agents=4, n_eps=4, n_steps=4000, iters=7, seed=0):
     """Health-on vs health-off A/B on one fleet run: wall-time overhead,
     off-mode bit-identity of every shared output, and the structural scan
-    gates. ``n_steps`` is raised above the config default for the same
-    reason as fig_profile's tracing arm: the overhead *fraction* only
-    means something against representative episode durations."""
+    gates. ``n_steps`` is raised above the config default: the overhead
+    *fraction* only means something against representative episode
+    durations."""
     cfg = FCPOConfig(n_steps=n_steps)
     health = HealthConfig()
     from repro.data.workload import fleet_traces

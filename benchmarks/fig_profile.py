@@ -1,27 +1,14 @@
-"""Flight-recorder benchmark: XLA accounting + tracing-overhead gates.
+"""Flight-recorder benchmark: XLA cost and memory accounting.
 
-Three measurements, one envelope (``BENCH_profile.json``):
-
-  * ``static`` — cost/memory accounting of the EXACT compiled programs the
-    training path runs: ``obs.profile.profile_fleet_scan`` lowers the same
-    ``_scan_fn`` the driver dispatches (donation included) and reads XLA's
-    ``cost_analysis``/``memory_analysis``; ``profile_kernels`` does the
-    same for every kernel jit in ``kernels.ops.KERNEL_JITS`` at its
-    canonical workload shape. The donation audit (every fleet leaf wired
-    to an aliased output in the stablehlo) is a ``--gate`` assertion — a
-    refactor that silently drops donation doubles training peak memory.
-  * ``tracing`` — the flight recorder's two contracts, measured:
-    (a) *off = free*: with no tracer the program is the pre-observability
-    one; (b) *on = cheap and bit-identical*: a traced run must produce a
-    bit-identical fleet + history (span callbacks never feed numerics) at
-    <= ``MAX_OVERHEAD_FRAC`` warm wall-clock overhead at default sampling,
-    and attaching a different tracer or sampling rate must NOT recompile
-    (trace-id and sample period are operands, not statics — the jit-cache
-    delta is asserted zero).
-  * the Chrome trace written by the traced run must validate against the
-    trace-event schema (``obs.validate_chrome_trace``) — the file is
-    exported next to the envelope (``trace_profile*.json``) and uploaded
-    as a CI artifact, so every push leaves an openable Perfetto timeline.
+One envelope (``BENCH_profile.json``): cost/memory accounting of the EXACT
+compiled programs the training path runs. ``obs.profile.profile_fleet_scan``
+lowers the same ``_scan_fn`` the driver dispatches (donation included) and
+reads XLA's ``cost_analysis``/``memory_analysis``; ``profile_kernels`` does
+the same for every kernel jit in ``kernels.ops.KERNEL_JITS`` at its
+canonical workload shape. The donation audit (every fleet leaf wired to an
+aliased output in the stablehlo) is a ``--gate`` assertion — a refactor
+that silently drops donation doubles training peak memory. Phase timing is
+the profiler's, measured on the chip by the ``bench/`` harness.
 
 Deltas: ``flops`` / ``bytes_accessed`` / ``peak_bytes`` against the
 previous envelope at the same path are attached as ``prev_*`` fields
@@ -30,23 +17,13 @@ previous envelope at the same path are attached as ``prev_*`` fields
 """
 from __future__ import annotations
 
-import os
-import time
-
 import jax
-import numpy as np
 
-from benchmarks.common import (BENCH_DIR, load_bench, load_rows, save_bench,
-                               save_rows)
+from benchmarks.common import load_bench, load_rows, save_bench, save_rows
 from repro.configs.fcpo import FCPOConfig
-from repro.core.fleet import _scan_fn, fleet_init, train_fleet_scan
-from repro.obs import Tracer, validate_chrome_trace
+from repro.core.fleet import fleet_init
 from repro.obs.profile import profile_fleet_scan, profile_kernels
 from repro.sim import make_scenario
-
-# Warm wall-clock overhead budget for tracing ON at default sampling
-# (span_sample_every=1, kernel spans off) vs the identical untraced run.
-MAX_OVERHEAD_FRAC = 0.05
 
 DELTA_METRICS = ("flops", "bytes_accessed", "peak_bytes")
 
@@ -66,106 +43,17 @@ def run_static(n_agents=8, episodes=4, seed=0):
     return rows
 
 
-def _min_wall_us(fn, iters):
-    """Min wall time per call in microseconds (the robust estimator for an
-    overhead *ratio* gate — medians of small samples flap on CI noise)."""
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        ts.append(time.perf_counter() - t0)
-    return float(min(ts) * 1e6)
-
-
-def run_tracing(n_agents=8, episodes=4, n_steps=3000, iters=5, seed=0,
-                trace_path=None):
-    """Traced-vs-untraced A/B on one fleet run: bit-identity, jit-cache
-    stability across tracer/sampling changes, warm overhead, and the
-    Chrome-trace schema check. ``trace_path``: where to export the traced
-    run's timeline (None: don't write).
-
-    ``n_steps`` is raised well above the config default (10): span emission
-    costs a fixed ~0.2-0.7 ms of ``io_callback`` dispatch per span edge
-    (measured; a ``lax.cond`` skip wrapper is *slower* — see
-    ``obs.trace._when_operand``), so the overhead *fraction* only means
-    something against a representative episode duration. Real training
-    episodes run 100+ ms; a 10-step toy episode is ~1.4 ms and would gate
-    on nothing but callback constants."""
-    cfg = FCPOConfig(n_steps=n_steps)
-    fleet = fleet_init(cfg, n_agents, jax.random.PRNGKey(seed))
-    traces = make_scenario("dynamic", jax.random.PRNGKey(seed + 1), n_agents,
-                           episodes * cfg.n_steps)
-    # donate=False so the same fleet pytree can be replayed for timing
-    run_off = lambda: train_fleet_scan(cfg, fleet, traces, donate=False)
-    f0, h0 = run_off()  # also the warmup/compile for the untraced variant
-
-    tracer = Tracer()  # defaults: every episode, no kernel spans
-    run_on = lambda: train_fleet_scan(cfg, fleet, traces, donate=False,
-                                      tracer=tracer)
-    f1, h1 = run_on()
-    bit_identical = all(
-        np.array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(jax.tree.leaves((f0, h0)), jax.tree.leaves((f1, h1))))
-
-    # a different Tracer object AND a different sampling period must reuse
-    # the cached executable: both are operands, not statics
-    size = _scan_fn(False)._cache_size()
-    with Tracer(span_sample_every=4) as sparse:
-        train_fleet_scan(cfg, fleet, traces, donate=False, tracer=sparse)
-    no_recompile = _scan_fn(False)._cache_size() == size
-
-    us_off = _min_wall_us(run_off, iters)
-    us_on = _min_wall_us(run_on, iters)
-    overhead_frac = us_on / max(us_off, 1e-9) - 1.0
-
-    trace = tracer.chrome_trace()
-    problems = validate_chrome_trace(trace)
-    n_slices = sum(1 for e in trace["traceEvents"] if e["ph"] == "X")
-    if trace_path is not None:
-        tracer.export(trace_path)
-    tracer.close()
-
-    return [{
-        "name": "profile_tracing_overhead",
-        "us_per_call": us_on,
-        "agents": n_agents,
-        "episodes": episodes,
-        "n_steps": n_steps,
-        "iters": iters,
-        "us_off": us_off,
-        "us_on": us_on,
-        "overhead_frac": overhead_frac,
-        "bit_identical": bool(bit_identical),
-        "no_recompile": bool(no_recompile),
-        "trace_slices": n_slices,
-        "trace_problems": len(problems),
-        "trace_path": trace_path or "",
-    }]
-
-
-def _trace_path(smoke: bool) -> str:
-    # Chrome traces land in artifacts/bench/ next to the BENCH_*.json
-    # envelopes (gitignored, uploaded by CI) — not the repo root.
-    os.makedirs(BENCH_DIR, exist_ok=True)
-    return os.path.join(BENCH_DIR,
-                        "trace_profile" + ("_smoke" if smoke else "") + ".json")
-
-
 def run(quick: bool = True, smoke: bool = False, fresh: bool = False):
     """Raw benchmark rows. ``smoke``: tiny CI shapes, never cached.
     ``fresh``: bypass the artifact cache (a regression gate must measure
     this run, not a stale artifact)."""
     if smoke:
-        return (run_static(n_agents=4, episodes=2)
-                + run_tracing(n_agents=4, episodes=4, n_steps=6000, iters=3,
-                              trace_path=_trace_path(True)))
+        return run_static(n_agents=4, episodes=2)
     if not fresh:
         cached = load_rows("fig_profile")
         if cached:
             return cached
-    rows = (run_static()
-            + run_tracing(iters=5 if quick else 11,
-                          trace_path=_trace_path(False)))
+    rows = run_static()
     save_rows("fig_profile", rows)
     return rows
 
@@ -194,23 +82,15 @@ def attach_prev(rows, prev_envelope):
 def format_rows(rows):
     out = []
     for r in rows:
-        if "overhead_frac" in r:
-            derived = (f"A={r['agents']} eps={r['episodes']} "
-                       f"overhead={r['overhead_frac'] * 100:+.2f}% "
-                       f"bit_identical={r['bit_identical']} "
-                       f"no_recompile={r['no_recompile']} "
-                       f"slices={r['trace_slices']} "
-                       f"schema_problems={r['trace_problems']}")
-        else:
-            derived = (f"flops={r['flops']:.3g} "
-                       f"bytes={r['bytes_accessed']:.3g} "
-                       f"peak={r['peak_bytes'] / 1e6:.2f}MB")
-            if "donation_ok" in r:
-                derived += (f" donated={r['donated_leaves']:.0f} "
-                            f"aliased={r['aliased_args']:.0f} "
-                            f"donation_ok={bool(r['donation_ok'])}")
-            if "delta_peak_bytes" in r:
-                derived += f" dpeak={r['delta_peak_bytes'] / 1e6:+.2f}MB"
+        derived = (f"flops={r['flops']:.3g} "
+                   f"bytes={r['bytes_accessed']:.3g} "
+                   f"peak={r['peak_bytes'] / 1e6:.2f}MB")
+        if "donation_ok" in r:
+            derived += (f" donated={r['donated_leaves']:.0f} "
+                        f"aliased={r['aliased_args']:.0f} "
+                        f"donation_ok={bool(r['donation_ok'])}")
+        if "delta_peak_bytes" in r:
+            derived += f" dpeak={r['delta_peak_bytes'] / 1e6:+.2f}MB"
         out.append({"name": r["name"],
                     "us_per_call": f"{r['us_per_call']:.0f}",
                     "derived": derived})
@@ -241,10 +121,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes for CI perf-path regression checks")
     ap.add_argument("--gate", action="store_true",
-                    help="exit nonzero unless the donation audit passes, "
-                         "tracing is bit-identical / recompile-free / "
-                         "within the overhead budget, and the Chrome "
-                         "trace validates (always re-measures)")
+                    help="exit nonzero unless the donation audit passes "
+                         "(always re-measures)")
     args = ap.parse_args()
     raw = _run_and_save(smoke=args.smoke, fresh=args.gate)
     emit_csv(format_rows(raw))
@@ -255,19 +133,3 @@ if __name__ == "__main__":
             f"outputs for {scan['donated_leaves']:.0f} donated fleet "
             f"leaves — a donated buffer is no longer reused in-place and "
             f"training peak memory roughly doubles")
-        tr = next(r for r in raw if r["name"] == "profile_tracing_overhead")
-        assert tr["bit_identical"], (
-            "traced run diverged from the untraced run — a span callback "
-            "is feeding the numerics; tracing must never change results")
-        assert tr["no_recompile"], (
-            "attaching a different tracer/sampling recompiled the scan — "
-            "trace id and sample period must stay operands, not statics")
-        assert tr["trace_problems"] == 0, (
-            f"Chrome trace failed schema validation "
-            f"({tr['trace_problems']} problems) — see "
-            f"obs.validate_chrome_trace")
-        assert tr["overhead_frac"] <= MAX_OVERHEAD_FRAC, (
-            f"tracing overhead {tr['overhead_frac'] * 100:.2f}% exceeds "
-            f"the {MAX_OVERHEAD_FRAC * 100:.0f}% budget at default "
-            f"sampling — span emission is too hot for an always-on "
-            f"flight recorder")

@@ -80,12 +80,16 @@ def run_episode(cfg: FCPOConfig, ep: env_mod.EnvParams, astate: AgentState,
         ys = (obs, actions, logp, reward, out["value"], probs, info)
         return (est2, rng), ys
 
-    (env_state, rng), ys = jax.lax.scan(
-        step, (astate.env_state, astate.rng), rates)
+    # profiler scopes: the benchmark's per-layer readers match these names
+    # in the device ops' name stacks (bench/layers/{rollout,buffer}_ms.py)
+    with jax.named_scope("fcpo_rollout"):
+        (env_state, rng), ys = jax.lax.scan(
+            step, (astate.env_state, astate.rng), rates)
     obs, actions, logp, rewards, values, probs, infos = ys
-    buffer = buffer_insert_batch(cfg, astate.buffer, obs, actions, logp,
-                                 rewards, values, probs,
-                                 use_pallas=use_pallas)
+    with jax.named_scope("fcpo_buffer"):
+        buffer = buffer_insert_batch(cfg, astate.buffer, obs, actions, logp,
+                                     rewards, values, probs,
+                                     use_pallas=use_pallas)
     rollout = Rollout(states=obs, actions=actions, logp_old=logp,
                       rewards=rewards, values_old=values)
     metrics = {
@@ -159,8 +163,9 @@ def crl_episode(cfg: FCPOConfig, ep: env_mod.EnvParams, astate: AgentState,
     astate, rollout, metrics = run_episode(cfg, ep, astate, rates, mask,
                                            backend=backend, health=health)
     if learn:
-        params, opt, lm = agent_update(cfg, astate.params, astate.opt,
-                                       rollout, mask)
+        with jax.named_scope("fcpo_update"):
+            params, opt, lm = agent_update(cfg, astate.params, astate.opt,
+                                           rollout, mask)
         astate = astate._replace(params=params, opt=opt)
         metrics = {**metrics, **lm}
     else:

@@ -28,6 +28,7 @@ from typing import Any, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.fcpo import FCPOConfig
@@ -45,7 +46,7 @@ from repro.fl import codec as fl_codec
 from repro.fl import staleness as fl_stale
 from repro.fl import transport as fl_transport
 from repro.fl.transport import DEFAULT_TRANSPORT, TransportConfig
-# the health observatory (repro.health) is a leaf layer like obs.trace:
+# the health observatory (repro.health) is a leaf layer:
 # pure pytree state + jnp ops, imports nothing from core, so the sketch /
 # drift / attribution updates stay inside the donated scan; health is a
 # jit-static config and the default (None) keeps the Fleet pytree and the
@@ -56,10 +57,6 @@ from repro.health import episode_summaries as health_summaries
 from repro.health import health_init
 from repro.health import update_episode as health_update_episode
 from repro.health import update_round as health_update_round
-# the flight-recorder span layer (repro.obs.trace) is a leaf utility —
-# imports jax only, so `core` stays cycle-free; tracing is a jit-static
-# flag and the default (off) path traces the exact span-free program
-from repro.obs import trace as obs_trace
 from repro.resilience import faults as rfaults
 from repro.resilience.faults import FaultConfig
 from repro.resilience.guards import DEFAULT_GUARDS, GuardConfig
@@ -105,7 +102,7 @@ class Fleet:
         # telemetry sketches, drift detectors, and attribution suspicion.
         # None (the default) flattens to an EMPTY subtree — the pytree, the
         # donation audit, and every traced program are bit-identical to
-        # pre-health fleets, the same mechanism the tracer used.
+        # pre-health fleets.
         self.health = health
         self.n_pods: int = n_pods
         self.group_counts: Dict[str, int] = group_counts
@@ -324,14 +321,12 @@ def fleet_episode(cfg: FCPOConfig, fleet: Fleet, rates: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnums=0,
-         static_argnames=("transport", "guards", "faults", "trace",
-                          "health"))
+         static_argnames=("transport", "guards", "faults", "health"))
 def fl_round(cfg: FCPOConfig, fleet: Fleet, rollouts, available=None,
              transport: Optional[TransportConfig] = None,
              guards: Optional[GuardConfig] = None,
              faults: Optional[FaultConfig] = None,
-             byzantine=None, fault_key=None, *, trace: bool = False,
-             trace_id=None, trace_when=None, trace_token=None,
+             byzantine=None, fault_key=None, *,
              health: Optional[HealthConfig] = None):
     """One federated round: transport -> Eq. 7 selection -> Alg. 1
     aggregation -> Alg. 2 head fine-tuning.
@@ -353,12 +348,11 @@ def fl_round(cfg: FCPOConfig, fleet: Fleet, rollouts, available=None,
     into the decoded deltas, post-codec. The defaults (no faults, mean
     aggregation, guards on) compile to the exact pre-chaos round.
 
-    ``trace`` (jit-static) + ``trace_id`` (plain operand — the registered
-    ``repro.obs.trace.Tracer`` id, so swapping tracers never recompiles)
-    bracket the round's phases (uplink model, codec encode/decode,
-    Algorithm 1 aggregation, Algorithm 2 fine-tuning) with flight-recorder
-    spans; ``trace_when`` optionally samples emission at runtime. The
-    default (trace off) compiles to the exact span-free round.
+    The stages run under the profiler scopes ``fl_uplink`` (link model),
+    ``fl_encode`` (the server-side view of the clients' parameters: codec
+    round trip or the lossless shortcut), ``fl_aggregate`` (Algorithm 1)
+    and ``fl_finetune`` (Algorithm 2); selection and the buffer resync sit
+    outside them, under ``jit(fl_round)``.
 
     ``health`` (jit-static, ``repro.health.HealthConfig``) attributes the
     round: every selected client's wire delta is scored against a
@@ -375,13 +369,9 @@ def fl_round(cfg: FCPOConfig, fleet: Fleet, rollouts, available=None,
     mask and ``fl_metrics`` the per-round communication/defense metrics
     (``repro.fl.transport.FL_METRIC_KEYS``)."""
     transport = DEFAULT_TRANSPORT if transport is None else transport
-    if trace and trace_id is None:
-        raise ValueError("fl_round(trace=True) needs a trace_id operand "
-                         "(a registered repro.obs.trace.Tracer id)")
     if health is not None and fleet.health is None:
         raise ValueError("fl_round(health=...) needs a fleet with health "
                          "state (fleet_init(..., health=...))")
-    tok = None
     guards = DEFAULT_GUARDS if guards is None else guards
     byz_on = faults is not None and faults.byzantine_active
     a = fleet.pod_ids.shape[0]
@@ -403,23 +393,15 @@ def fl_round(cfg: FCPOConfig, fleet: Fleet, rollouts, available=None,
         rejected = rejected + n_dropped
 
     # --- communication model: payload sizes are static, links are per-agent
-    if trace:
-        # trace_token: the caller's enclosing span-begin token — making it a
-        # dep of the first inner begin orders the callbacks outer-begin ->
-        # inner-begin (unordered io_callbacks only order by data flow)
-        tok = obs_trace.span_begin("fl/uplink", trace_id, fleet.bandwidth,
-                                   trace_token, when=trace_when)
-    up_bytes = fl_transport.agent_payload_bytes(params, transport,
-                                               stacked=True)
-    full_bytes = fl_transport.full_param_bytes(params, stacked=True)
-    down_bytes = fl_transport.downlink_bytes(transport, a, fleet.n_pods,
-                                             up_bytes, full_bytes)
-    uplink_s = fl_transport.uplink_seconds(up_bytes, fleet.bandwidth)
-    on_time = fl_transport.on_time_mask(uplink_s, transport.deadline_s)
-    fresh_ok = legacy_avail & on_time
-    if trace:
-        tok = obs_trace.span_end("fl/uplink", trace_id, tok, fresh_ok,
-                                 when=trace_when)
+    with jax.named_scope("fl_uplink"):
+        up_bytes = fl_transport.agent_payload_bytes(params, transport,
+                                                   stacked=True)
+        full_bytes = fl_transport.full_param_bytes(params, stacked=True)
+        down_bytes = fl_transport.downlink_bytes(transport, a, fleet.n_pods,
+                                                 up_bytes, full_bytes)
+        uplink_s = fl_transport.uplink_seconds(up_bytes, fleet.bandwidth)
+        on_time = fl_transport.on_time_mask(uplink_s, transport.deadline_s)
+        fresh_ok = legacy_avail & on_time
 
     # --- Eq. 7 selection. Sync rounds: a slow link emergently drops out of
     # selection. Async rounds: slow-but-alive clients stay selectable (they
@@ -453,144 +435,131 @@ def fl_round(cfg: FCPOConfig, fleet: Fleet, rollouts, available=None,
     )(params, rollouts, fleet.masks)
 
     # --- reconstruct the server-side view of each client's parameters
-    if transport.plain and not byz_on and guards.clip_factor <= 0:
-        # lossless codec, nothing parked, nothing corrupted or clipped in
-        # transit: base + (params - base) == params identically — skip the
-        # delta machinery so the default config is bit-for-bit the
-        # pre-transport program.
-        recon, sel_agg = params, sel
-        residuals, new_pending = fleet.residuals, pending
-        transmitted = sel
-        stale_used = jnp.zeros((), jnp.float32)
-        if guards.reject_nonfinite:
-            # identity on healthy params; a wedged client (NaN'd by its own
-            # training) drops out of aggregation instead of poisoning it
-            ok = guard_finite_mask(params)
-            rejected = rejected + jnp.sum(sel & ~ok).astype(jnp.float32)
-            sel_agg = sel & ok
-            health_rej = sel & ~ok
-        if health is not None:
-            # pure readout on the side: the shortcut above still aggregates
-            # the raw params, so the plain-path numerics stay bit-identical
-            # to health-off — the deltas vs the downlinked base exist only
-            # to be scored
-            base_h = jax.tree.map(
+    with jax.named_scope("fl_encode"):
+        if transport.plain and not byz_on and guards.clip_factor <= 0:
+            # lossless codec, nothing parked, nothing corrupted or clipped
+            # in transit: base + (params - base) == params identically —
+            # skip the delta machinery so the default config is bit-for-bit
+            # the pre-transport program.
+            recon, sel_agg = params, sel
+            residuals, new_pending = fleet.residuals, pending
+            transmitted = sel
+            stale_used = jnp.zeros((), jnp.float32)
+            if guards.reject_nonfinite:
+                # identity on healthy params; a wedged client (NaN'd by its
+                # own training) drops out of aggregation instead of
+                # poisoning it
+                ok = guard_finite_mask(params)
+                rejected = rejected + jnp.sum(sel & ~ok).astype(jnp.float32)
+                sel_agg = sel & ok
+                health_rej = sel & ~ok
+            if health is not None:
+                # pure readout on the side: the shortcut above still
+                # aggregates the raw params, so the plain-path numerics stay
+                # bit-identical to health-off — the deltas vs the downlinked
+                # base exist only to be scored
+                base_h = jax.tree.map(
+                    lambda b: shd.agent_hint(b[fleet.pod_ids]
+                                             .astype(jnp.float32)),
+                    fleet.base_params)
+                delta_h = jax.tree.map(
+                    lambda p, b: jnp.subtract(p.astype(jnp.float32), b),
+                    params, base_h)
+                susp_new = health_attribution(delta_h, sel_agg)["susp"]
+        else:
+            # The (P,...)->(A,...) gather is the round's downlink broadcast:
+            # the agent hint lets a meshed run materialize it shard-local
+            # instead of full-replica. Deltas are formed in float32 whatever
+            # the storage policy (bf16 params would otherwise difference at
+            # bf16). Both are no-ops under the default f32/no-mesh config.
+            base_g = jax.tree.map(
                 lambda b: shd.agent_hint(b[fleet.pod_ids]
                                          .astype(jnp.float32)),
                 fleet.base_params)
-            delta_h = jax.tree.map(
+            delta = jax.tree.map(
                 lambda p, b: jnp.subtract(p.astype(jnp.float32), b),
-                params, base_h)
-            susp_new = health_attribution(delta_h, sel_agg)["susp"]
-    else:
-        if trace:
-            tok = obs_trace.span_begin("fl/encode", trace_id, params, tok,
-                                       when=trace_when)
-        # The (P,...)->(A,...) gather is the round's downlink broadcast: the
-        # agent hint lets a meshed run materialize it shard-local instead of
-        # full-replica. Deltas are formed in float32 whatever the storage
-        # policy (bf16 params would otherwise difference at bf16). Both are
-        # no-ops under the default f32/no-mesh config.
-        base_g = jax.tree.map(
-            lambda b: shd.agent_hint(b[fleet.pod_ids].astype(jnp.float32)),
-            fleet.base_params)
-        delta = jax.tree.map(
-            lambda p, b: jnp.subtract(p.astype(jnp.float32), b),
-            params, base_g)
-        # bind the trace-id operand so a Pallas codec kernel called in here
-        # (transport.use_pallas) emits its kernel span against the same
-        # tracer — binding None (trace off) is a no-op
-        with obs_trace.bind_tid(trace_id if trace else None):
+                params, base_g)
             decoded, res_next = fl_codec.codec_roundtrip(
                 delta, fleet.residuals, transport)
-        if byz_on:
-            # corruption happens in transit, AFTER the honest client
-            # encoded its delta and committed error feedback — the server
-            # sees garbage, the client's own state stays consistent
-            key = (fault_key if fault_key is not None
-                   else jax.random.PRNGKey(faults.seed))
-            decoded = rfaults.corrupt_deltas(faults, decoded, byzantine, key)
-        if transport.async_rounds:
-            w_stale = fl_stale.stale_weights(pending,
-                                             transport.staleness_decay)
-            contrib = fl_stale.merge_contributions(decoded, pending,
-                                                   fresh_ok, w_stale)
-            sel_agg = sel & (fresh_ok | pending.has)
-            parked = sel & legacy_avail & ~on_time
-            consumed = sel & pending.has & ~fresh_ok
-            fresh_sent = sel & fresh_ok
-            transmitted = fresh_sent | parked
-            new_pending = fl_stale.update_pending(pending, decoded, parked,
-                                                  consumed, fresh_sent)
-            stale_used = jnp.sum(consumed).astype(jnp.float32)
-        else:
-            contrib = decoded
-            sel_agg = sel            # selection already required on-time
-            transmitted = sel
-            new_pending = pending
-            stale_used = jnp.zeros((), jnp.float32)
-        # --- server-side defenses on the merged wire contributions ---
-        if guards.reject_nonfinite:
-            ok = guard_finite_mask(contrib)
-            rejected = rejected + jnp.sum(sel_agg & ~ok).astype(jnp.float32)
-            health_rej = sel_agg & ~ok
-            sel_agg = sel_agg & ok
-        if health is not None:
-            # score the post-corruption wire deltas BEFORE clipping — the
-            # clip would erase exactly the magnitude evidence the norm
-            # term keys on
-            susp_new = health_attribution(contrib, sel_agg)["susp"]
-        if guards.clip_factor > 0:
-            contrib, clipped = guard_clip_deltas(contrib, sel_agg,
-                                                 guards.clip_factor)
-        # only selected contributors are seen through the wire; everyone
-        # else enters aggregation with their TRUE params, so Alg. 1's
-        # no-contributor fallback ("groups with no contributor keep the
-        # agent's own head") keeps real heads, not a lossy reconstruction
-        # whose error feedback was never committed.
-        recon = jax.tree.map(
-            lambda rc, p: jnp.where(
-                sel_agg.reshape((-1,) + (1,) * (rc.ndim - 1)), rc,
-                p.astype(rc.dtype)),
-            jax.tree.map(jnp.add, base_g, contrib), params)
-        # error feedback commits only for deltas that actually went (or
-        # will go, parked) over the wire; everyone else re-derives a fresh
-        # delta against the moved base next round. The codec returns f32
-        # residuals; they are stored back at StatePolicy.transport precision.
-        residuals = jax.tree.map(
-            lambda nr, r: jnp.where(
-                transmitted.reshape((-1,) + (1,) * (nr.ndim - 1)),
-                nr.astype(r.dtype), r),
-            res_next, fleet.residuals)
-        if trace:
-            tok = obs_trace.span_end("fl/encode", trace_id, tok, recon,
-                                     when=trace_when)
+            if byz_on:
+                # corruption happens in transit, AFTER the honest client
+                # encoded its delta and committed error feedback — the server
+                # sees garbage, the client's own state stays consistent
+                key = (fault_key if fault_key is not None
+                       else jax.random.PRNGKey(faults.seed))
+                decoded = rfaults.corrupt_deltas(faults, decoded, byzantine,
+                                                 key)
+            if transport.async_rounds:
+                w_stale = fl_stale.stale_weights(pending,
+                                                 transport.staleness_decay)
+                contrib = fl_stale.merge_contributions(decoded, pending,
+                                                       fresh_ok, w_stale)
+                sel_agg = sel & (fresh_ok | pending.has)
+                parked = sel & legacy_avail & ~on_time
+                consumed = sel & pending.has & ~fresh_ok
+                fresh_sent = sel & fresh_ok
+                transmitted = fresh_sent | parked
+                new_pending = fl_stale.update_pending(pending, decoded, parked,
+                                                      consumed, fresh_sent)
+                stale_used = jnp.sum(consumed).astype(jnp.float32)
+            else:
+                contrib = decoded
+                sel_agg = sel            # selection already required on-time
+                transmitted = sel
+                new_pending = pending
+                stale_used = jnp.zeros((), jnp.float32)
+            # --- server-side defenses on the merged wire contributions ---
+            if guards.reject_nonfinite:
+                ok = guard_finite_mask(contrib)
+                rejected = rejected + jnp.sum(sel_agg & ~ok).astype(
+                    jnp.float32)
+                health_rej = sel_agg & ~ok
+                sel_agg = sel_agg & ok
+            if health is not None:
+                # score the post-corruption wire deltas BEFORE clipping — the
+                # clip would erase exactly the magnitude evidence the norm
+                # term keys on
+                susp_new = health_attribution(contrib, sel_agg)["susp"]
+            if guards.clip_factor > 0:
+                contrib, clipped = guard_clip_deltas(contrib, sel_agg,
+                                                     guards.clip_factor)
+            # only selected contributors are seen through the wire; everyone
+            # else enters aggregation with their TRUE params, so Alg. 1's
+            # no-contributor fallback ("groups with no contributor keep the
+            # agent's own head") keeps real heads, not a lossy reconstruction
+            # whose error feedback was never committed.
+            recon = jax.tree.map(
+                lambda rc, p: jnp.where(
+                    sel_agg.reshape((-1,) + (1,) * (rc.ndim - 1)), rc,
+                    p.astype(rc.dtype)),
+                jax.tree.map(jnp.add, base_g, contrib), params)
+            # error feedback commits only for deltas that actually went (or
+            # will go, parked) over the wire; everyone else re-derives a fresh
+            # delta against the moved base next round. The codec returns f32
+            # residuals; they are stored back at StatePolicy.transport
+            # precision.
+            residuals = jax.tree.map(
+                lambda nr, r: jnp.where(
+                    transmitted.reshape((-1,) + (1,) * (nr.ndim - 1)),
+                    nr.astype(r.dtype), r),
+                res_next, fleet.residuals)
 
-    if trace:
-        tok = obs_trace.span_begin("fl/aggregate", trace_id, recon, tok,
-                                   when=trace_when)
     # Algorithm 1 computes in float32 (recon may arrive bf16 off the plain
     # path under a lean model policy); the new fleet/base params are stored
     # back at the policy dtype — all astype identities under the default.
-    new_params, new_base = fed.aggregate(
-        cfg, dtp.tree_f32(recon), dtp.tree_f32(fleet.base_params), sel_agg,
-        head_losses, fleet.head_groups, fleet.pod_ids, fleet.n_pods,
-        method=guards.agg, trim_frac=guards.trim_frac)
-    new_params = dtp.tree_cast_like(new_params, params)
-    new_base = dtp.tree_cast_like(new_base, fleet.base_params)
-    if trace:
-        tok = obs_trace.span_end("fl/aggregate", trace_id, tok, new_params,
-                                 when=trace_when)
-        tok = obs_trace.span_begin("fl/finetune", trace_id, new_params, tok,
-                                   when=trace_when)
+    with jax.named_scope("fl_aggregate"):
+        new_params, new_base = fed.aggregate(
+            cfg, dtp.tree_f32(recon), dtp.tree_f32(fleet.base_params),
+            sel_agg, head_losses, fleet.head_groups, fleet.pod_ids,
+            fleet.n_pods, method=guards.agg, trim_frac=guards.trim_frac)
+        new_params = dtp.tree_cast_like(new_params, params)
+        new_base = dtp.tree_cast_like(new_base, fleet.base_params)
 
     # Algorithm 2: local action-head fine-tuning on local experiences
-    params, opt = jax.vmap(
-        lambda p, o, r, m: finetune_heads(cfg, p, o, r, m)
-    )(new_params, fleet.astate.opt, rollouts, fleet.masks)
-    if trace:
-        tok = obs_trace.span_end("fl/finetune", trace_id, tok, params,
-                                 when=trace_when)
+    with jax.named_scope("fl_finetune"):
+        params, opt = jax.vmap(
+            lambda p, o, r, m: finetune_heads(cfg, p, o, r, m)
+        )(new_params, fleet.astate.opt, rollouts, fleet.masks)
 
     # FL-round cadence is the off-hot-path slot to resync the buffers'
     # streaming moments from their slots, bounding rank-1 float32 drift.
@@ -607,11 +576,6 @@ def fl_round(cfg: FCPOConfig, fleet: Fleet, rollouts, available=None,
         "fl_rejected": rejected,
         "fl_clipped": clipped,
     }
-    if trace:
-        # hand the final inner token back so the caller's enclosing span_end
-        # is ordered after the last inner end callback (popped before the
-        # metrics dict reaches the history)
-        fl_metrics["_trace_tok"] = tok
     new_health = fleet.health
     if health is not None:
         # a rejected contribution is maximal evidence — the client shipped
@@ -676,7 +640,6 @@ def train_fleet_reference(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                           guards: Optional[GuardConfig] = None,
                           episode_offset: int = 0,
                           total_episodes: Optional[int] = None,
-                          tracer=None,
                           health: Optional[HealthConfig] = None):
     """The original Python-loop driver: one host dispatch per episode plus a
     per-metric host sync — O(n_episodes) dispatches. Kept as the equivalence
@@ -684,10 +647,7 @@ def train_fleet_reference(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
     same fault plan). ``metrics_sink`` gets the same per-episode records as
     the scan driver's streaming tap, appended directly from the loop.
     ``faults``/``guards``/``episode_offset``/``total_episodes``/``health``
-    mirror ``train_fleet_scan``. ``tracer`` records host-side episode /
-    fl_round spans (this driver dispatches per episode, so plain wall
-    bracketing is already phase-accurate; sampling follows
-    ``span_sample_every``)."""
+    mirror ``train_fleet_scan``."""
     backend = get_backend(env_backend)
     transport = DEFAULT_TRANSPORT if transport is None else transport
     faults, guards = _normalize_chaos(faults, guards)
@@ -709,11 +669,6 @@ def train_fleet_reference(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
     history: Dict[str, list] = {}
     rounds = int(schedule[:episode_offset].sum())
 
-    def hspan(name, e):  # sampled host-side span, no-op without a tracer
-        if tracer is not None and e % tracer.span_sample_every == 0:
-            return tracer.span(name, cat="phase")
-        return nullcontext()
-
     for e in range(episode_offset):  # burn the pre-offset straggler draws
         if schedule[e]:
             rng.random(a)
@@ -721,12 +676,10 @@ def train_fleet_reference(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
         i = e - episode_offset
         rates = traces[:, i * cfg.n_steps:(i + 1) * cfg.n_steps]
         prev_astate = fleet.astate
-        with hspan("episode", e):
-            fleet, rollouts, metrics = fleet_episode(cfg, fleet, rates,
-                                                     learn=learn,
-                                                     backend=backend,
-                                                     health=health)
-            jax.block_until_ready(metrics)
+        fleet, rollouts, metrics = fleet_episode(cfg, fleet, rates,
+                                                 learn=learn,
+                                                 backend=backend,
+                                                 health=health)
         ran = None
         if crash_on:
             fleet, ran, down = rfaults.apply_crashes(
@@ -739,14 +692,12 @@ def train_fleet_reference(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
             fkey = (jax.random.fold_in(jax.random.PRNGKey(faults.seed), e)
                     if byz_on else None)
             pre_round = fleet.astate
-            with hspan("fl_round", e):
-                fleet, _, fl_metrics = fl_round(
-                    cfg, fleet, rollouts, avail, transport=transport,
-                    guards=guards, faults=faults,
-                    byzantine=(jnp.asarray(plan.byzantine[e]) if byz_on
-                               else None),
-                    fault_key=fkey, health=health)
-                jax.block_until_ready(fl_metrics)
+            fleet, _, fl_metrics = fl_round(
+                cfg, fleet, rollouts, avail, transport=transport,
+                guards=guards, faults=faults,
+                byzantine=(jnp.asarray(plan.byzantine[e]) if byz_on
+                           else None),
+                fault_key=fkey, health=health)
             if crash_on:
                 # a down agent is offline: it must not receive the round's
                 # new model (it rejoins later via the step-① warm start)
@@ -813,11 +764,10 @@ def _scan_driver(cfg: FCPOConfig, fleet: Fleet, rates_eps: jnp.ndarray,
                  avail: jnp.ndarray, do_fl: jnp.ndarray, ep_idx: jnp.ndarray,
                  sink_id: jnp.ndarray, crash_eps: jnp.ndarray,
                  byz_eps: jnp.ndarray, part_eps: jnp.ndarray,
-                 rounds0: jnp.ndarray, trace_id: jnp.ndarray,
-                 trace_sample: jnp.ndarray, learn: bool,
+                 rounds0: jnp.ndarray, learn: bool,
                  backend: EnvBackend, transport: TransportConfig,
                  faults: Optional[FaultConfig],
-                 guards: GuardConfig, stream: bool, trace: bool,
+                 guards: GuardConfig, stream: bool,
                  health: Optional[HealthConfig]):
     """Scan body host fn. rates_eps: (n_eps, A, n_steps); avail/do_fl/ep_idx:
     pre-drawn availability bits, FL schedule, and (absolute) episode
@@ -830,11 +780,7 @@ def _scan_driver(cfg: FCPOConfig, fleet: Fleet, rates_eps: jnp.ndarray,
     callback — the run is still ONE dispatch, but the sink's JSONL file
     tails live. Meshed runs use the unordered flavor (ordered effects are
     single-device-only); the scan's sequential data dependence still
-    fires it once per episode. ``trace`` (static) +
-    ``trace_id``/``trace_sample`` (operands) bracket the episode / FL-round
-    / pod-merge phases with flight-recorder spans on every
-    ``trace_sample``-th episode — same one-dispatch run, and the trace-off
-    program is the exact span-free one. ``health`` (static) advances the
+    fires it once per episode. ``health`` (static) advances the
     observatory state through every episode and FL round (sketches, drift
     detectors, attribution) — all pure pytree ops inside the scan; None
     stages the exact health-free program."""
@@ -845,17 +791,10 @@ def _scan_driver(cfg: FCPOConfig, fleet: Fleet, rates_eps: jnp.ndarray,
     def body(carry, xs):
         flt, rounds = carry
         rates, av, fl, ep_i, crash, byz, px = xs
-        when = (ep_i % trace_sample == 0) if trace else None
-        if trace:
-            tok_ep = obs_trace.span_begin("episode", trace_id, rates,
-                                          when=when)
         prev_astate = flt.astate
         flt, rollouts, metrics = fleet_episode(cfg, flt, rates, learn=learn,
                                                backend=backend,
                                                health=health)
-        if trace:
-            tok_ep = obs_trace.span_end("episode", trace_id, tok_ep,
-                                        metrics, when=when)
         ran = down = None
         if crash_on:
             flt, ran, down = rfaults.apply_crashes(faults, prev_astate, flt,
@@ -867,25 +806,10 @@ def _scan_driver(cfg: FCPOConfig, fleet: Fleet, rates_eps: jnp.ndarray,
             fkey = (jax.random.fold_in(jax.random.PRNGKey(faults.seed), ep_i)
                     if byz_on else None)
             pre_round = f.astate
-            if trace:
-                tok_fl = obs_trace.span_begin("fl_round", trace_id,
-                                              f.bandwidth, tok_ep, when=when)
             f, _, flm = fl_round(cfg, f, rollouts, av, transport=transport,
                                  guards=guards, faults=faults,
                                  byzantine=byz if byz_on else None,
-                                 fault_key=fkey, trace=trace,
-                                 trace_id=trace_id if trace else None,
-                                 trace_when=when,
-                                 trace_token=tok_fl if trace else None,
-                                 health=health)
-            if trace:
-                # the popped inner token orders this end after the round's
-                # last inner end callback (and keeps the metrics dict shapes
-                # identical across the fl/no-fl cond branches)
-                tok_fl = obs_trace.span_end("fl_round", trace_id, tok_fl,
-                                            flm.pop("_trace_tok"),
-                                            flm["fl_payload_bytes"],
-                                            when=when)
+                                 fault_key=fkey, health=health)
             if crash_on:
                 # a down agent is offline: it must not receive the round's
                 # new model (it rejoins later via the step-① warm start)
@@ -894,16 +818,8 @@ def _scan_driver(cfg: FCPOConfig, fleet: Fleet, rates_eps: jnp.ndarray,
             rnd = rnd + 1
             if f.n_pods > 1:
                 def merge(g):
-                    if trace:
-                        tm = obs_trace.span_begin("pod_merge", trace_id,
-                                                  g.base_params, tok_fl,
-                                                  when=when)
-                    g = (pod_merge(cfg, g, px, faults=faults) if part_on
-                         else pod_merge(cfg, g))
-                    if trace:
-                        obs_trace.span_end("pod_merge", trace_id, tm,
-                                           g.base_params, when=when)
-                    return g
+                    return (pod_merge(cfg, g, px, faults=faults) if part_on
+                            else pod_merge(cfg, g))
                 f = jax.lax.cond(rnd % cfg.hierarchical_period == 0,
                                  merge, lambda g: g, f)
             return (f, rnd), flm
@@ -937,7 +853,7 @@ _SCAN_FNS: Dict[bool, Any] = {}
 
 def _scan_fn(donate: bool):
     if donate not in _SCAN_FNS:
-        kw = dict(static_argnums=(0, 13, 14, 15, 16, 17, 18, 19, 20))
+        kw = dict(static_argnums=(0, 11, 12, 13, 14, 15, 16, 17))
         if donate:
             kw["donate_argnums"] = (1,)
         _SCAN_FNS[donate] = jax.jit(_scan_driver, **kw)
@@ -948,7 +864,7 @@ def _prep_scan_args(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                     learn, federated, straggler_prob, seed, mesh,
                     env_backend, transport, faults, guards,
                     episode_offset, total_episodes,
-                    sink_id, stream, tracer, health=None):
+                    sink_id, stream, health=None):
     """Host-side argument prep shared by ``train_fleet_scan`` and
     ``lower_fleet_scan``: FL schedule, availability draws, fault plan,
     episode-major rate reshape, optional mesh sharding — returns the exact
@@ -987,14 +903,10 @@ def _prep_scan_args(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
             x, NamedSharding(mesh, shd.agent_batch_spec(x.shape, mesh)))
         rates_eps, avail = xs_shard(rates_eps), xs_shard(avail)
 
-    trace = tracer is not None
-    tid = tracer.tid if trace else 0
-    tsamp = tracer.span_sample_every if trace else 1
     return (cfg, fleet, rates_eps, avail, do_fl, ep_idx,
             jnp.asarray(sink_id, jnp.int32), crash_eps, byz_eps, part_eps,
-            jnp.asarray(rounds0, jnp.int32), jnp.asarray(tid, jnp.int32),
-            jnp.asarray(tsamp, jnp.int32), learn, backend, transport,
-            faults, guards, stream, trace, health)
+            jnp.asarray(rounds0, jnp.int32), learn, backend, transport,
+            faults, guards, stream, health)
 
 
 def _mesh_context(mesh):
@@ -1020,7 +932,7 @@ def lower_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                            straggler_prob, seed, mesh, env_backend,
                            transport, faults, guards, episode_offset,
                            total_episodes, sink_id=0, stream=False,
-                           tracer=None, health=health)
+                           health=health)
     # trace under the mesh so the in-graph sharding hints
     # (sharding.ambient_mesh) resolve — the analyzed program is the meshed
     # program train_fleet_scan would run
@@ -1039,7 +951,6 @@ def train_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                      guards: Optional[GuardConfig] = None,
                      episode_offset: int = 0,
                      total_episodes: Optional[int] = None,
-                     tracer=None,
                      health: Optional[HealthConfig] = None):
     """Scanned fleet driver: episodes over ``traces`` (A, total_steps), FL
     every ``fl_every`` episodes (stragglers masked by pre-drawn availability
@@ -1084,13 +995,6 @@ def train_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
     hierarchical-merge counter all follow the *absolute* episode index, so
     a run chunked across checkpoint save/restore boundaries is
     value-identical to the uninterrupted run.
-    ``tracer``: a ``repro.obs.trace.Tracer`` — flight-recorder spans for
-    the episode / FL-round (encode, uplink, aggregate, finetune) /
-    pod-merge phases, emitted from inside the single dispatch by host
-    callbacks on every ``tracer.span_sample_every``-th episode. Off (None)
-    by default, in which case the traced program is exactly the span-free
-    one; the tracer object is addressed by a non-static integer id, so
-    re-tracing the same-shaped run with a fresh tracer never recompiles.
     ``health``: a jit-static ``repro.health.HealthConfig`` — the fleet
     health observatory: per-agent telemetry sketches + drift detectors
     advanced per control interval, FL contribution attribution per round,
@@ -1101,7 +1005,13 @@ def train_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
     the traced program is exactly the health-free one — bit-identical
     histories, unchanged donation audit.
     Returns (fleet, history) with history as per-episode numpy arrays,
-    fetched in a single device->host transfer."""
+    fetched in a single device->host transfer.
+
+    Under the JAX profiler the host stages land as ``fleet.prep`` (argument
+    prep and transfer), ``fleet.call`` (the jitted call's dispatch) and
+    ``fleet.fetch`` (the history fetch, which waits for the device), on
+    the clock of the device ops; the compiled scan's stages carry the
+    ``fcpo_*`` and ``fl_*`` named scopes."""
     if donate is None:
         donate = jax.default_backend() != "cpu"
     # ordered callbacks are a single-device-only effect in XLA; on a multi-
@@ -1110,28 +1020,29 @@ def train_fleet_scan(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
     stream = False if metrics_sink is None else \
         ("ordered" if mesh is None or mesh.size == 1 else "unordered")
     sid = _register_sink(metrics_sink) if stream else 0
-    args = _prep_scan_args(cfg, fleet, traces, learn, federated,
-                           straggler_prob, seed, mesh, env_backend,
-                           transport, faults, guards, episode_offset,
-                           total_episodes, sink_id=sid, stream=stream,
-                           tracer=tracer, health=health)
+    with TraceAnnotation("fleet.prep"):
+        args = _prep_scan_args(cfg, fleet, traces, learn, federated,
+                               straggler_prob, seed, mesh, env_backend,
+                               transport, faults, guards, episode_offset,
+                               total_episodes, sink_id=sid, stream=stream,
+                               health=health)
     try:
         # setting the mesh as the context mesh activates the in-graph sharding
         # hints (agents over (pod, data), pods over the FL hierarchy): the
         # Alg. 1 segment-sums and the pod merge lower to real collectives.
         # Without a mesh the hints are no-ops and the traced program is the
         # exact single-device one.
-        with obs_trace.activate(tracer), _mesh_context(mesh):
-            fleet, history = _scan_fn(bool(donate))(*args)
-            history = jax.device_get(history)
+        with _mesh_context(mesh):
+            with TraceAnnotation("fleet.call"):
+                fleet, history = _scan_fn(bool(donate))(*args)
+            with TraceAnnotation("fleet.fetch"):
+                history = jax.device_get(history)
     finally:
         if stream:
             # the history fetch blocks on the compute; the callback effects
             # drain behind it — barrier before releasing the sink slot
             jax.effects_barrier()
             _METRIC_SINKS.pop(sid, None)
-        if tracer is not None:
-            tracer.drain()
     return fleet, history
 
 
@@ -1140,7 +1051,7 @@ def train_fleet(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                 straggler_prob: float = 0.0, seed: int = 0,
                 env_backend=None, transport: Optional[TransportConfig] = None,
                 metrics_sink=None, faults: Optional[FaultConfig] = None,
-                guards: Optional[GuardConfig] = None, tracer=None,
+                guards: Optional[GuardConfig] = None,
                 health: Optional[HealthConfig] = None):
     """Compatibility entry point — delegates to the scanned driver. Buffer
     donation stays off so callers may keep using the input fleet (forking a
@@ -1150,5 +1061,4 @@ def train_fleet(cfg: FCPOConfig, fleet: Fleet, traces: jnp.ndarray,
                             straggler_prob=straggler_prob, seed=seed,
                             donate=False, env_backend=env_backend,
                             transport=transport, metrics_sink=metrics_sink,
-                            faults=faults, guards=guards, tracer=tracer,
-                            health=health)
+                            faults=faults, guards=guards, health=health)

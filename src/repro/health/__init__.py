@@ -1,11 +1,11 @@
 """Fleet health observatory: learning-dynamics state inside the jitted scan.
 
-The contract mirrors PR 8's tracer: health is an *optional* field of the
-``Fleet`` pytree. ``None`` (the default) flattens to an empty subtree, so
-disabled runs stage the exact pre-PR program — bit-identical histories,
-unchanged golden tests, unchanged donation audit. Enabled, the state is a
-``HealthState`` of agent-leading float32 leaves updated by pure pytree ops
-(no host callbacks on the hot path):
+Health is an *optional* field of the ``Fleet`` pytree. ``None`` (the
+default) flattens to an empty subtree, so disabled runs stage the exact
+health-free program — bit-identical histories, unchanged golden tests,
+unchanged donation audit. Enabled, the state is a ``HealthState`` of
+agent-leading float32 leaves updated by pure pytree ops (no host callbacks
+on the hot path):
 
 * per-episode, inside ``run_episode``'s metrics tail: telemetry sketches
   (``sketch.py``) + drift detectors (``drift.py``) consume the episode's

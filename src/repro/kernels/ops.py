@@ -9,20 +9,10 @@ The model code defaults to the jnp reference path under dry-run
 (identical math — see DESIGN.md §6) and switches to these via
 ``use_pallas=True``.
 
-Flight-recorder hook: every wrapper consults
-``repro.obs.trace.kernel_trace_tid()``. When it returns None (the default:
-no active tracer, or inside an un-instrumented trace) the call goes through
-the same cached jit wrapper as before this layer existed — the exact
-pre-observability program. When a tracer with ``kernel_spans=True`` is
-active at the top level (or an instrumented caller has bound a trace-id via
-``bind_tid``), the call routes to a *traced twin* — same kernel, bracketed
-by ``kernel/<name>`` spans — jitted separately with the trace-id as a plain
-operand, so per-kernel timing never recompiles per tracer and never leaks
-into the untraced cache.
+Each wrapper is one ``jax.jit`` of its ``_<kernel>_impl`` function, so a
+profiler trace names the kernel's op after it (``_queue_advance_impl.<n>``).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 from jax.sharding import PartitionSpec as P
@@ -34,7 +24,6 @@ from repro.kernels import diversity as _div
 from repro.kernels import flash_attention as _fa
 from repro.kernels import packing as _pack
 from repro.kernels import queue_advance as _qa
-from repro.obs import trace as obs_trace
 
 
 def _interpret_default() -> bool:
@@ -42,26 +31,6 @@ def _interpret_default() -> bool:
     there), compiled Pallas on every accelerator backend (TPU -> Mosaic,
     GPU -> Triton)."""
     return jax.default_backend() == "cpu"
-
-
-def _twins(name, impl, static_argnames=()):
-    """Build (untraced, traced) jitted variants of kernel ``impl``. The
-    untraced one is the original wrapper; the traced one takes the trace-id
-    as its first (non-static) operand and brackets the kernel with
-    ``kernel/<name>`` spans."""
-    untraced = functools.partial(jax.jit, static_argnames=static_argnames)(
-        impl) if static_argnames else jax.jit(impl)
-
-    def traced_impl(tid, *args, **kw):
-        tok = obs_trace.span_begin(f"kernel/{name}", tid, args,
-                                   cat="kernel")
-        out = impl(*args, **kw)
-        obs_trace.span_end(f"kernel/{name}", tid, tok, out)
-        return out
-
-    traced = (functools.partial(jax.jit, static_argnames=static_argnames)(
-        traced_impl) if static_argnames else jax.jit(traced_impl))
-    return untraced, traced
 
 
 def _flash_impl(q, k, v, *, causal=True, bq=128, bk=128):
@@ -98,37 +67,30 @@ def _queue_advance_impl(arrive, counters, credits, lat_sum, hist, arrivals,
                              arrivals, caps, interpret=_interpret_default())
 
 
-_FLASH = _twins("flash_attention", _flash_impl, ("causal", "bq", "bk"))
-_DECODE = _twins("decode_attention", _decode_impl, ("bk",))
-_PACK = _twins("pack", _pack_impl)
-_DIVERSITY = _twins("diversity_insert", _diversity_impl,
-                    ("alpha", "beta", "ridge"))
-_DELTA_CODEC = _twins("delta_codec", _delta_codec_impl, ("codec", "k"))
-_QUEUE_ADVANCE = _twins("queue_advance", _queue_advance_impl)
+_FLASH = jax.jit(_flash_impl, static_argnames=("causal", "bq", "bk"))
+_DECODE = jax.jit(_decode_impl, static_argnames=("bk",))
+_PACK = jax.jit(_pack_impl)
+_DIVERSITY = jax.jit(_diversity_impl, static_argnames=("alpha", "beta",
+                                                       "ridge"))
+_DELTA_CODEC = jax.jit(_delta_codec_impl, static_argnames=("codec", "k"))
+_QUEUE_ADVANCE = jax.jit(_queue_advance_impl)
 
 
-def _dispatch(twins, args, kw, agent_batched=None):
-    """Call the untraced or the traced twin. ``agent_batched`` marks a fleet
+def _dispatch(kernel, args, kw, agent_batched=None):
+    """Call the jitted ``kernel``. ``agent_batched`` marks a fleet
     kernel: True when ``args`` lead with the agent dim, False when they are
     one agent's (the call then sits under the fleet's ``vmap``). Under an
     ambient mesh such a kernel runs in ``shard_map``, each device on its
     own agents: Mosaic kernels cannot be partitioned automatically, and
     agents are independent. Unbatched operands are replicated there; the
     fleet's ``vmap(spmd_axis_name=...)`` shards the agent dim it adds."""
-    tid = obs_trace.kernel_trace_tid()
-    if tid is None:
-        call = lambda *a: twins[0](*a, **kw)
-    else:
-        args = (tid,) + tuple(args)
-        call = lambda t, *a: twins[1](t, *a, **kw)
+    call = lambda *a: kernel(*a, **kw)
     mesh = None if agent_batched is None else shd.ambient_mesh()
     if mesh is None:
         return call(*args)
     agent = P(shd.agent_axes(args[-1].shape[0], mesh)) if agent_batched \
         else P()
-    in_specs = tuple(P() if tid is not None and i == 0 else agent
-                     for i in range(len(args)))
-    return jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(call, mesh=mesh, in_specs=(agent,) * len(args),
                          out_specs=agent, check_vma=False)(*args)
 
 
@@ -177,15 +139,14 @@ def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps):
                       caps), {}, agent_batched=arrive.ndim == 2)
 
 
-# name -> untraced jit wrapper — the profiler (repro.obs.profile) uses
-# these to lower and cost/memory-account every kernel variant; they are the
-# exact objects the dispatchers call, so the analyzed program is the one
-# that runs.
+# name -> jit wrapper — the profiler (repro.obs.profile) uses these to lower
+# and cost/memory-account every kernel variant; they are the exact objects
+# the dispatchers call, so the analyzed program is the one that runs.
 KERNEL_JITS = {
-    "flash_attention": _FLASH[0],
-    "decode_attention": _DECODE[0],
-    "pack": _PACK[0],
-    "diversity_insert": _DIVERSITY[0],
-    "delta_codec": _DELTA_CODEC[0],
-    "queue_advance": _QUEUE_ADVANCE[0],
+    "flash_attention": _FLASH,
+    "decode_attention": _DECODE,
+    "pack": _PACK,
+    "diversity_insert": _DIVERSITY,
+    "delta_codec": _DELTA_CODEC,
+    "queue_advance": _QUEUE_ADVANCE,
 }

@@ -147,10 +147,9 @@ def main(argv=None):
         if args.trace_out:
             from repro.obs.trace import Tracer
 
-            with Tracer() as tr:
-                n = obs_requests.records_to_chrome(tr, attr["records"],
-                                                   sp.dt)
-                tr.export(args.trace_out)
+            tr = Tracer()
+            n = obs_requests.records_to_chrome(tr, attr["records"], sp.dt)
+            tr.export(args.trace_out)
             print(f"wrote {n} request slices -> {args.trace_out} "
                   f"(open in Perfetto / chrome://tracing)")
 

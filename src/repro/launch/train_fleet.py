@@ -142,13 +142,14 @@ def parse_args(argv=None):
                          "JSONL file while training runs; tail it live with "
                          "python -m repro.launch.watch <file> --follow")
     ap.add_argument("--trace-out", type=str, default=None,
-                    help="flight recorder: record phase spans (episode, "
-                         "fl_round encode/uplink/aggregate, pod merge) "
-                         "from inside the compiled run and write Chrome "
-                         "trace-event JSON here (open in Perfetto)")
-    ap.add_argument("--trace-sample", type=int, default=1,
-                    help="record spans only on every Nth episode (runtime "
-                         "sampling — changing it never recompiles)")
+                    metavar="DIR",
+                    help="run training under the JAX profiler and write its "
+                         "trace to this directory (plugins/profile/<time>/"
+                         "*.trace.json.gz and perfetto_trace.json.gz, open "
+                         "in Perfetto): device ops under the fcpo_rollout/"
+                         "fcpo_buffer/fcpo_update and fl_uplink/fl_encode/"
+                         "fl_aggregate/fl_finetune scopes, host spans "
+                         "fleet.prep/fleet.call/fleet.fetch, on one clock")
     # --- fleet health observatory (repro.health) ---
     ap.add_argument("--health", action="store_true",
                     help="attach the fleet health observatory: per-agent "
@@ -270,8 +271,6 @@ def parse_args(argv=None):
                  "driver; drop --driver reference")
     if args.ckpt_every < 0 or args.stop_after < 0 or args.keep_last < 1:
         ap.error("--ckpt-every/--stop-after must be >= 0, --keep-last >= 1")
-    if args.trace_sample < 1:
-        ap.error("--trace-sample must be >= 1")
     if args.susp_threshold and not args.health:
         ap.error("--susp-threshold gates selection on the suspicion EMA "
                  "the observatory maintains; add --health")
@@ -287,7 +286,7 @@ def parse_args(argv=None):
 def build(args):
     """The run ``args`` describe: (cfg, fleet, traces, mesh, kw) with ``kw``
     the keywords ``train_fleet_scan`` / ``lower_fleet_scan`` take besides
-    the metrics sink and the tracer."""
+    the metrics sink."""
     cfg = FCPOConfig() if args.fl_every is None else \
         FCPOConfig(fl_every=args.fl_every)
     faults = FaultConfig(
@@ -374,12 +373,14 @@ def main(argv=None):
         # forwarded AND evaluated against the rulebook
         engine = AlertEngine(args.alerts_out, forward=sink)
         kw["metrics_sink"] = engine
-    tracer = None
     if args.trace_out:
-        from repro.obs.trace import Tracer
-
-        tracer = Tracer(span_sample_every=args.trace_sample)
-        kw["tracer"] = tracer
+        # jax.profiler.trace's start/stop pair, spanning the try below; the
+        # Python tracer stays off (its events, compilation's above all,
+        # would fill the trace file's million-event cap before the run's)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(args.trace_out, create_perfetto_trace=True,
+                                 profiler_options=opts)
     t0 = time.time()
     try:
         if args.ckpt_dir:
@@ -448,12 +449,10 @@ def main(argv=None):
             engine.close()  # closes the forwarded sink too
         elif sink is not None:
             sink.close()
-        if tracer is not None:
-            tracer.export(args.trace_out)
-            print(f"flight recorder: "
-                  f"{len(tracer.chrome_events())} span events -> "
-                  f"{args.trace_out} (open in Perfetto)")
-            tracer.close()
+        if args.trace_out:
+            jax.profiler.stop_trace()
+            print(f"profiler trace -> {args.trace_out} (open its "
+                  f"perfetto_trace.json.gz in Perfetto)")
 
     n_run = len(np.asarray(hist["reward"]))
     k = max(n_run // 10, 1)

@@ -1,26 +1,20 @@
-"""Flight recorder: span tracing, XLA cost/memory accounting, and
-request-grade latency attribution.
+"""Flight recorder: XLA cost/memory accounting, request-grade latency
+attribution, and the Chrome trace-event writer they export through.
 
-Three coordinated layers over the same run:
-
-* ``repro.obs.trace`` — phase-level spans (episode -> fl_round
-  encode/uplink/aggregate -> pod merge, plus per-kernel spans) emitted
-  from inside the single jitted scan by host callbacks, exported as
-  Chrome trace-event JSON (Perfetto / chrome://tracing).
 * ``repro.obs.profile`` — ``cost_analysis``/``memory_analysis`` of the
   compiled fleet scan and each kernel variant, plus the donation audit;
   persisted via ``benchmarks.common.save_bench`` as ``BENCH_profile``.
 * ``repro.obs.requests`` — sampled per-request lifecycle records
   reconstructed from the twin's monotone stage counters, decomposing
   tail latency into per-stage queueing / service / batching delay.
+* ``repro.obs.trace`` — Chrome trace-event JSON (Perfetto /
+  chrome://tracing) for those request timelines.
 
-``core`` may import ``repro.obs.trace`` (a leaf, jax-only module); the
-other two layers sit above ``core``/``sim`` and must not be imported
-from them.
+Phase timing of the training program is the JAX profiler's: named scopes
+inside the compiled scan and ``TraceAnnotation`` host spans in
+``core.fleet.train_fleet_scan`` (see ``repro.obs.trace``). ``profile`` and
+``requests`` sit above ``core``/``sim`` and must not be imported from them.
 """
-from repro.obs.trace import (Tracer, active_tracer, bind_tid,
-                             kernel_trace_tid, span_begin, span_end,
-                             validate_chrome_trace)
+from repro.obs.trace import Tracer, validate_chrome_trace
 
-__all__ = ["Tracer", "active_tracer", "bind_tid", "kernel_trace_tid",
-           "span_begin", "span_end", "validate_chrome_trace"]
+__all__ = ["Tracer", "validate_chrome_trace"]

@@ -1,6 +1,6 @@
 """XLA cost & memory accounting: the flight recorder's static layer.
 
-Where ``repro.obs.trace`` records when phases ran, this module records what
+Where the profiler's trace records when phases ran, this module records what
 the compiled programs *are*: FLOPs and bytes accessed from
 ``Compiled.cost_analysis()``, argument/output/temp/alias sizes from
 ``Compiled.memory_analysis()``, and a donation audit that checks the fleet

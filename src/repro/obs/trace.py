@@ -1,295 +1,38 @@
-"""Span tracing: the flight recorder's timeline layer.
+"""Chrome trace-event JSON: a writer and its schema check.
 
-A ``Tracer`` collects phase-level spans — host ``perf_counter_ns``
-timestamps bracketing regions of the compiled program — and exports them
-as Chrome trace-event JSON that opens directly in Perfetto /
-``chrome://tracing``. Spans are emitted from INSIDE jitted code (the
-single scanned fleet driver, ``fl_round``'s transport phases, the Pallas
-kernel wrappers) through ``jax.experimental.io_callback`` pairs whose
-float tokens chain begin -> compute -> end by *data dependency*, so the
-recorded intervals bracket the real execution order without ordered
-effects (which ``lax.cond`` branches — where the FL phases live — do not
-admit).
+The training program's own spans live in the JAX profiler's trace, on one
+clock with the device ops: ``jax.named_scope``s inside the compiled scan
+(``fcpo_rollout``, ``fcpo_buffer``, ``fcpo_update``, ``fl_uplink``,
+``fl_encode``, ``fl_aggregate``, ``fl_finetune``) and
+``jax.profiler.TraceAnnotation``s around the host stages of
+``core.fleet.train_fleet_scan`` (``fleet.prep``, ``fleet.call``,
+``fleet.fetch``). ``launch/train_fleet.py --trace-out DIR`` records them.
 
-Two invariants the rest of the repo leans on:
-
-* **Off = the exact pre-trace program.** Tracing is a jit-static flag
-  threaded through the instrumented entry points (``train_fleet_scan``'s
-  ``tracer=``, ``fl_round``'s ``trace=``); with it off (the default) no
-  callback is traced and the compiled program — and therefore the run
-  history — is bit-identical to the pre-observability code
-  (golden-checked in tests/test_obs.py).
-* **No recompile per tracer.** The tracer is addressed by an integer id
-  passed to the compiled program as a plain (non-static) operand — the
-  same registry trick as the metrics-sink tap in ``core/fleet.py`` — so
-  attaching a different ``Tracer`` object to a same-shaped run reuses
-  the cached executable.
-
-The callback outputs never feed back into the numeric computation: a
-begin token flows only into its end callback (and into nested begins),
-so the traced-with-spans program computes bit-identical values to the
-span-free one — tracing ON changes wall-clock, never numerics.
-
-``span_sample_every`` thins emission *at runtime*: the
-``episode % sample_every == 0`` predicate rides into each callback as a
-data operand and the HOST drops sampled-out events. (A traced ``lax.cond``
-around the callback was measured slower than the callback it skips — the
-effect-carrying cond blocks XLA:CPU fusion at every span site.) The
-predicate is data, not a static, so dialing sampling up or down never
-recompiles.
+This module writes the timelines that have a clock of their own: the
+request lifecycles ``obs.requests.records_to_chrome`` reconstructs on the
+twin's virtual time (``launch/simulate.py --trace-out``). The file opens
+in Perfetto / ``chrome://tracing``.
 """
 from __future__ import annotations
 
 import json
 import os
-import threading
-import time
-from contextlib import contextmanager
-from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.experimental import io_callback
-
-# ---------------------------------------------------------------------------
-# Tracer registry: id -> Tracer, addressed from compiled code by operand
-# ---------------------------------------------------------------------------
-_TRACERS: Dict[int, "Tracer"] = {}
-_NEXT_ID = [1]
-_LOCK = threading.Lock()
-
-# trace-time binding of the *current* trace-id value (a jax tracer while a
-# traced function body executes, a concrete array at the top level). The
-# kernel wrappers in ``kernels/ops.py`` read it so a kernel called inside a
-# traced ``fl_round(trace=True)`` emits spans against the SAME operand id
-# as the enclosing phases — never a baked-in constant.
-_BOUND_TID: List[Any] = []
-_ACTIVE: List["Tracer"] = []
-
-_F32 = jax.ShapeDtypeStruct((), jnp.float32)
+from typing import Any, Dict, List, Optional
 
 
-def register_tracer(tracer: "Tracer") -> int:
-    with _LOCK:
-        tid = _NEXT_ID[0]
-        _NEXT_ID[0] += 1
-        _TRACERS[tid] = tracer
-    return tid
-
-
-def release_tracer(tid: int) -> None:
-    with _LOCK:
-        _TRACERS.pop(int(tid), None)
-
-
-def get_tracer(tid: int) -> Optional["Tracer"]:
-    return _TRACERS.get(int(tid))
-
-
-@contextmanager
-def bind_tid(tid):
-    """Trace-time context: make ``tid`` (operand value) visible to nested
-    instrumentation (the kernel wrappers) during tracing of an instrumented
-    function body."""
-    _BOUND_TID.append(tid)
-    try:
-        yield
-    finally:
-        _BOUND_TID.pop()
-
-
-def bound_tid():
-    return _BOUND_TID[-1] if _BOUND_TID else None
-
-
-@contextmanager
-def activate(tracer: "Tracer"):
-    """Host-level context: mark ``tracer`` active so eager (non-traced)
-    instrumentation — the kernel wrappers called at the top level, the
-    reference driver's host spans — records into it. ``None`` is a no-op
-    so callers can thread an optional tracer straight through."""
-    if tracer is None:
-        yield None
-        return
-    _ACTIVE.append(tracer)
-    try:
-        yield tracer
-    finally:
-        _ACTIVE.pop()
-
-
-def active_tracer() -> Optional["Tracer"]:
-    return _ACTIVE[-1] if _ACTIVE else None
-
-
-def kernel_trace_tid():
-    """The trace-id the kernel wrappers should emit against, or None when
-    kernel spans must stay off this call.
-
-    Inside a traced instrumented body (``bind_tid``): the bound operand.
-    At the top level (``jax.core.trace_state_clean()``): the active
-    tracer's id, if it opted into kernel spans. Inside any OTHER trace
-    (e.g. an un-instrumented jitted fn compiled while a tracer happens to
-    be active): None — spans must never bake into a cached program whose
-    jit key does not know about them."""
-    b = bound_tid()
-    if b is not None:
-        return b
-    t = active_tracer()
-    if (t is not None and t.kernel_spans
-            and jax.core.trace_state_clean()):
-        return jnp.asarray(t.tid, jnp.int32)
-    return None
-
-
-# ---------------------------------------------------------------------------
-# Host callback targets
-# ---------------------------------------------------------------------------
-def _now_us() -> float:
-    return time.perf_counter_ns() / 1e3
-
-
-def _cb_begin(name: str, cat: str, tid_arr, when_arr, *_probes) -> np.float32:
-    if not bool(when_arr):
-        return np.float32(0.0)
-    tracer = get_tracer(int(tid_arr))
-    if tracer is not None:
-        tracer._begin(name, cat)
-    return np.float32(1.0)
-
-
-def _cb_end(name: str, tid_arr, when_arr, _tok, *_probes) -> np.float32:
-    if not bool(when_arr):
-        return np.float32(0.0)
-    tracer = get_tracer(int(tid_arr))
-    if tracer is not None:
-        tracer._end(name)
-    return np.float32(1.0)
-
-
-def _cb_instant(name: str, cat: str, tid_arr, when_arr, *_probes) -> np.float32:
-    if not bool(when_arr):
-        return np.float32(0.0)
-    tracer = get_tracer(int(tid_arr))
-    if tracer is not None:
-        tracer.instant(name, cat)
-    return np.float32(1.0)
-
-
-def _probe(x):
-    """A 0-d float32 window into ``x`` — the data dependency that pins a
-    span callback into the execution order (first leaf, first element)."""
-    leaves = jax.tree.leaves(x)
-    if not leaves:
-        return jnp.float32(0.0)
-    leaf = leaves[0]
-    if jnp.ndim(leaf) == 0:
-        return jnp.asarray(leaf, jnp.float32)
-    return jnp.asarray(jnp.ravel(leaf)[0], jnp.float32)
-
-
-def _when_operand(when):
-    """The sampling predicate as a callback operand. A traced ``lax.cond``
-    wrapper was measured SLOWER than just making the host call and letting
-    it drop the sampled-out event: the effect-carrying cond blocks XLA:CPU
-    fusion around every span site (~14% on the fleet scan even with the
-    predicate always false), while the bare callback costs ~0.1 ms. So the
-    predicate rides INTO the callback as data and the host filters."""
-    return jnp.asarray(True if when is None else when, jnp.bool_)
-
-
-def span_begin(name: str, tid, *deps, cat: str = "phase", when=None):
-    """Open span ``name`` from inside jitted code. ``tid``: the trace-id
-    operand. ``deps``: values the span's phase consumes — their probes
-    order the begin callback after the phase inputs exist. Returns a float
-    token: thread it into ``span_end`` (and into nested ``span_begin``
-    deps) to enforce begin -> body -> end ordering. ``when``: optional
-    traced bool — emission sampled at runtime (host-filtered), no
-    recompile."""
-    probes = [_probe(d) for d in deps]
-    return io_callback(partial(_cb_begin, name, cat), _F32,
-                       tid, _when_operand(when), *probes)
-
-
-def span_end(name: str, tid, token, *outputs, when=None):
-    """Close span ``name``: ``token`` is the matching ``span_begin``'s
-    return; ``outputs`` are values the phase produced — their probes order
-    the end callback after the phase completes. Returns a token usable as
-    a dep of the next phase."""
-    probes = [_probe(o) for o in outputs]
-    return io_callback(partial(_cb_end, name), _F32,
-                       tid, _when_operand(when), token, *probes)
-
-
-def instant(name: str, tid, *deps, cat: str = "mark", when=None):
-    """A zero-duration instant event (Chrome ``ph: "i"``)."""
-    probes = [_probe(d) for d in deps]
-    return io_callback(partial(_cb_instant, name, cat), _F32,
-                       tid, _when_operand(when), *probes)
-
-
-# ---------------------------------------------------------------------------
-# The recorder
-# ---------------------------------------------------------------------------
 class Tracer:
-    """Flight-recorder event collector + Chrome trace-event exporter.
+    """Collects complete slices and exports them as Chrome trace-event
+    JSON (the ``traceEvents`` container format)."""
 
-    ``span_sample_every``: emit the per-episode spans of the scanned fleet
-    driver only on every N-th episode (runtime-sampled — the predicate is
-    data, so changing it never recompiles). ``kernel_spans``: let the
-    ``kernels/ops.py`` wrappers record per-kernel spans when called at the
-    top level or inside an instrumented trace.
-
-    Events live in memory as (name, cat, ph, ts_us, dur_us) tuples; begin/
-    end pairs are folded into complete ``X`` slices at ``_end`` time via a
-    per-tracer span stack (tolerant: an end that skips stack levels closes
-    the inner spans at the same timestamp instead of corrupting the file).
-    Host-side phases (compile, device fetch) bracket with ``span()``.
-    """
-
-    def __init__(self, span_sample_every: int = 1,
-                 kernel_spans: bool = False, pid: int = 1):
-        assert span_sample_every >= 1
-        self.span_sample_every = int(span_sample_every)
-        self.kernel_spans = bool(kernel_spans)
+    def __init__(self, pid: int = 1):
         self.pid = pid
         self.events: List[Dict[str, Any]] = []
-        self._stack: List[Tuple[str, str, float]] = []
-        self._lock = threading.Lock()
-        self.tid = register_tracer(self)
-
-    # -- recording (called from the jax callback thread / host code) ------
-    def _begin(self, name: str, cat: str):
-        with self._lock:
-            self._stack.append((name, cat, _now_us()))
-
-    def _end(self, name: str):
-        now = _now_us()
-        with self._lock:
-            while self._stack:
-                n, cat, t0 = self._stack.pop()
-                self.events.append({"name": n, "cat": cat, "ph": "X",
-                                    "ts": t0, "dur": max(now - t0, 0.0),
-                                    "pid": self.pid, "tid": 0})
-                if n == name:
-                    return
-            # unmatched end: record an instant so the anomaly is visible
-            self.events.append({"name": name, "cat": "unmatched-end",
-                                "ph": "i", "ts": now, "s": "t",
-                                "pid": self.pid, "tid": 0})
-
-    def instant(self, name: str, cat: str = "mark"):
-        self.events.append({"name": name, "cat": cat, "ph": "i",
-                            "ts": _now_us(), "s": "t",
-                            "pid": self.pid, "tid": 0})
 
     def add_complete(self, name: str, ts_us: float, dur_us: float,
                      cat: str = "request", pid: Optional[int] = None,
                      tid: int = 0, args: Optional[Dict] = None):
-        """Append a pre-formed complete slice (the request-attribution
-        exporter uses this with virtual twin-time timestamps)."""
+        """Append a complete slice with caller-given timestamps (the
+        request-attribution exporter passes virtual twin-time)."""
         ev = {"name": name, "cat": cat, "ph": "X", "ts": float(ts_us),
               "dur": float(max(dur_us, 0.0)),
               "pid": self.pid if pid is None else pid, "tid": tid}
@@ -297,36 +40,8 @@ class Tracer:
             ev["args"] = args
         self.events.append(ev)
 
-    @contextmanager
-    def span(self, name: str, cat: str = "host"):
-        """Host-side span (compile, fetch, file IO): plain wall bracketing,
-        no callbacks involved."""
-        self._begin(name, cat)
-        try:
-            yield
-        finally:
-            self._end(name)
-
-    # -- export -----------------------------------------------------------
-    def drain(self):
-        """Flush any still-open spans (e.g. the run was interrupted) as
-        zero-duration instants so the export is always well-formed."""
-        jax.effects_barrier()
-        with self._lock:
-            while self._stack:
-                n, cat, t0 = self._stack.pop()
-                self.events.append({"name": n, "cat": cat + "-open",
-                                    "ph": "i", "ts": t0, "s": "t",
-                                    "pid": self.pid, "tid": 0})
-
-    def chrome_events(self) -> List[Dict[str, Any]]:
-        self.drain()
-        return sorted(self.events, key=lambda e: e["ts"])
-
     def chrome_trace(self) -> Dict[str, Any]:
-        """The Chrome trace-event JSON object (``traceEvents`` container
-        format) — opens directly in Perfetto / chrome://tracing."""
-        return {"traceEvents": self.chrome_events(),
+        return {"traceEvents": sorted(self.events, key=lambda e: e["ts"]),
                 "displayTimeUnit": "ms"}
 
     def export(self, path: str) -> str:
@@ -336,19 +51,7 @@ class Tracer:
             json.dump(self.chrome_trace(), f, default=float)
         return path
 
-    def close(self):
-        release_tracer(self.tid)
 
-    def __enter__(self) -> "Tracer":
-        return self
-
-    def __exit__(self, *exc):
-        self.close()
-
-
-# ---------------------------------------------------------------------------
-# Schema validation (shared by the tests and the fig_profile gate)
-# ---------------------------------------------------------------------------
 REQUIRED_KEYS = ("name", "ph", "ts", "pid", "tid")
 VALID_PH = {"X", "B", "E", "i", "I", "M", "C", "b", "e", "s", "t", "f"}
 

@@ -1,16 +1,17 @@
-"""Flight-recorder tests: span tracing, request attribution, live-metrics
+"""Flight-recorder tests: profiler spans, request attribution, live-metrics
 resilience.
 
 Four invariant families:
 
-  * tracing changes NOTHING numeric — a traced ``train_fleet_scan`` run is
-    bit-identical to the untraced one (span callbacks never feed the
-    numerics), and with no tracer the compiled program is the exact
-    pre-observability one (tests/test_golden.py pins that run; here the
-    traced twin is compared leaf-for-leaf against it transitively);
-  * the exported timeline is well-formed — Chrome trace-event schema
-    round-trips through JSON, span timestamps are monotone and properly
-    nested, sampling thins emission without recompiling;
+  * the profiler sees the program's stages and changes nothing — the
+    lowered scan carries every ``fcpo_*``/``fl_*`` named scope (the names
+    the benchmark's per-layer readers match), the host stages of
+    ``train_fleet_scan`` land as ``fleet.prep`` < ``fleet.call`` <
+    ``fleet.fetch`` annotations, the kernel wrappers keep their jit names,
+    and a run under ``jax.profiler.trace`` is bit-identical to one without
+    and reuses its executable;
+  * the exported request timeline is well-formed — Chrome trace-event
+    schema round-trips through JSON;
   * request attribution is a lossless decomposition — per-request stage
     stamps reconstructed from the twin's monotone counters conserve the
     twin's own aggregate counts/latency-sum/histogram EXACTLY (including a
@@ -21,9 +22,12 @@ Four invariant families:
     ``launch/watch.py`` degrades gracefully on meta-only files and unknown
     metric keys.
 """
+import glob
+import gzip
 import json
 import os
-from collections import Counter
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -31,13 +35,16 @@ import numpy as np
 import pytest
 
 from repro.configs.fcpo import FCPOConfig
-from repro.core.fleet import _scan_fn, fleet_init, train_fleet_scan
+from repro.core.backends import get_backend
+from repro.core.fleet import (_scan_fn, fleet_init, lower_fleet_scan,
+                              train_fleet_scan)
 from repro.eval.stream import MetricsSink, read_metrics
+from repro.fl import TransportConfig
+from repro.kernels import ops as kernel_ops
 from repro.kernels.ref import (CAP_BATCH, CAP_POST, CAP_PRE, CAP_QCAP,
                                CAP_SLO, CAP_TBATCH)
 from repro.launch import watch
 from repro.obs import Tracer, validate_chrome_trace
-from repro.obs import trace as obs_trace
 from repro.obs.requests import SEGMENTS, attribute_agent, attribute_run, \
     conservation_report, records_to_chrome, stage_decomposition
 from repro.sim import SimParams, make_scenario, simulate_fleet
@@ -47,127 +54,142 @@ from repro.sim.step import sim_interval_recorded
 A, EPISODES, SEED = 4, 4, 0
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCOPES = ("fcpo_rollout", "fcpo_buffer", "fcpo_update", "fl_uplink",
+          "fl_encode", "fl_aggregate", "fl_finetune")
+HOST_SPANS = ("fleet.prep", "fleet.call", "fleet.fetch")
+
+
+def _profile_events(log_dir):
+    """The complete events of the profiler trace written under
+    ``log_dir``."""
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.trace.json.gz"))
+    with gzip.open(path, "rt") as f:
+        return [e for e in json.load(f)["traceEvents"] if e.get("ph") == "X"]
+
+
 # ---------------------------------------------------------------------------
-# One traced/untraced run pair shared by the span tests (two scan compiles)
+# Profiler spans: named scopes in the compiled scan, host annotations
 # ---------------------------------------------------------------------------
+def _lowered_scan_text(backend_name):
+    """The scan a benchmark cell dispatches, lowered with its debug info:
+    fluid with the lossless transport, the twin with both kernels and the
+    int8 codec (interpret-mode Pallas on the CPU)."""
+    cfg = FCPOConfig()
+    twin = backend_name == "twin"
+    backend = get_backend(backend_name, use_pallas=twin)
+    fleet = fleet_init(cfg, A, jax.random.PRNGKey(SEED), n_pods=2,
+                       env_backend=backend)
+    traces = make_scenario("nominal", jax.random.PRNGKey(SEED + 1), A,
+                           2 * cfg.n_steps)
+    transport = (TransportConfig(codec="int8", use_pallas=True) if twin
+                 else None)
+    return lower_fleet_scan(cfg, fleet, traces, donate=False,
+                            env_backend=backend,
+                            transport=transport).as_text(debug_info=True)
+
+
 @pytest.fixture(scope="module")
-def traced_runs():
+def lowered_scans():
+    """Each backend's lowered scan text, made once on first use."""
+    texts = {}
+
+    def get(backend_name):
+        if backend_name not in texts:
+            texts[backend_name] = _lowered_scan_text(backend_name)
+        return texts[backend_name]
+    return get
+
+
+@pytest.fixture(scope="module")
+def profiled_runs(tmp_path_factory):
+    """The same two-call run without and then under the profiler."""
     cfg = FCPOConfig()
     fleet = fleet_init(cfg, A, jax.random.PRNGKey(SEED))
     traces = make_scenario("nominal", jax.random.PRNGKey(SEED + 1), A,
                            EPISODES * cfg.n_steps)
     kw = dict(seed=SEED, donate=False)
-    off = train_fleet_scan(cfg, fleet, traces, **kw)
-    t1 = Tracer()
-    on = train_fleet_scan(cfg, fleet, traces, tracer=t1, **kw)
-    ev_full = t1.chrome_events()
-    t1.close()
-    size_after_first = _scan_fn(False)._cache_size()
-    t2 = Tracer(span_sample_every=2)
-    on2 = train_fleet_scan(cfg, fleet, traces, tracer=t2, **kw)
-    ev_sparse = t2.chrome_events()
-    t2.close()
-    size_after_second = _scan_fn(False)._cache_size()
-    return {"cfg": cfg, "off": off, "on": on, "on2": on2,
-            "ev_full": ev_full, "ev_sparse": ev_sparse,
-            "cache_sizes": (size_after_first, size_after_second)}
+    off = [train_fleet_scan(cfg, fleet, traces, **kw) for _ in range(2)]
+    size = _scan_fn(False)._cache_size()
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=opts):
+        on = [train_fleet_scan(cfg, fleet, traces, **kw) for _ in range(2)]
+    return {"off": off, "on": on, "cache_sizes": (size, _scan_fn(
+        False)._cache_size()), "events": _profile_events(log_dir)}
 
 
-class TestSpanTracing:
-    def test_traced_run_bit_identical(self, traced_runs):
-        """Span emission must never change the numerics — tracing ON (at
-        any sampling) computes the same bits as OFF. (OFF vs the pre-PR
-        program is pinned by tests/test_golden.py.)"""
-        for other in ("on", "on2"):
-            for a, b in zip(jax.tree.leaves(traced_runs["off"]),
-                            jax.tree.leaves(traced_runs[other])):
-                assert np.array_equal(np.asarray(a), np.asarray(b))
+class TestProfilerSpans:
+    @pytest.mark.parametrize("backend", ["fluid", "twin"])
+    @pytest.mark.parametrize("scope", SCOPES)
+    def test_lowered_scan_carries_scope(self, lowered_scans, backend,
+                                        scope):
+        """Every stage the benchmark's readers split the device time by is
+        a named scope in the op name stacks of the compiled scan."""
+        text = lowered_scans(backend)
+        assert f"{scope}/" in text or f"({scope})" in text
 
-    def test_tracer_swap_does_not_recompile(self, traced_runs):
-        """Trace-id and sampling period are operands, not statics: a second
-        tracer with a different sampling rate reuses the executable."""
-        first, second = traced_runs["cache_sizes"]
-        assert second == first
+    def test_profiled_run_bit_identical(self, profiled_runs):
+        """The profiler records; it never changes the numerics."""
+        for a, b in zip(jax.tree.leaves(profiled_runs["off"]),
+                        jax.tree.leaves(profiled_runs["on"])):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
 
-    def test_span_names_and_counts(self, traced_runs):
-        counts = Counter(e["name"] for e in traced_runs["ev_full"]
-                         if e["ph"] == "X")
-        assert counts["episode"] == EPISODES
-        # fl_every=2 -> rounds complete on episodes 1 and 3
-        assert counts["fl_round"] == 2
-        for phase in ("fl/uplink", "fl/aggregate", "fl/finetune"):
-            assert counts[phase] == 2, counts
-        # every begin found its end: no unmatched/open anomaly markers
-        bad = [e for e in traced_runs["ev_full"]
-               if e.get("cat", "").endswith("-open")
-               or e.get("cat") == "unmatched-end"]
-        assert not bad, bad
+    def test_profiled_run_does_not_recompile(self, profiled_runs):
+        before, after = profiled_runs["cache_sizes"]
+        assert after == before
 
-    def test_spans_monotone_and_nested(self, traced_runs):
-        ev = [e for e in traced_runs["ev_full"] if e["ph"] == "X"]
-        eps = sorted((e for e in ev if e["name"] == "episode"),
-                     key=lambda e: e["ts"])
-        # episodes are sequential, non-overlapping, non-negative duration
-        for e in eps:
-            assert e["dur"] >= 0
-        for prev, nxt in zip(eps, eps[1:]):
-            assert nxt["ts"] >= prev["ts"] + prev["dur"]
-        # every FL phase span nests inside some fl_round span
-        rounds = [e for e in ev if e["name"] == "fl_round"]
-        for e in ev:
-            if not e["name"].startswith("fl/"):
-                continue
-            assert any(r["ts"] <= e["ts"] and
-                       e["ts"] + e["dur"] <= r["ts"] + r["dur"]
-                       for r in rounds), (e, rounds)
+    def test_host_spans_in_order_once_per_call(self, profiled_runs):
+        spans = sorted((e for e in profiled_runs["events"]
+                        if e["name"] in HOST_SPANS), key=lambda e: e["ts"])
+        assert [e["name"] for e in spans] == list(HOST_SPANS) * 2
+        assert len({e["tid"] for e in spans}) == 1
+        for prev, nxt in zip(spans, spans[1:]):
+            assert prev["ts"] + prev["dur"] <= nxt["ts"]
 
-    def test_sampling_thins_emission(self, traced_runs):
-        counts = Counter(e["name"] for e in traced_runs["ev_sparse"]
-                         if e["ph"] == "X")
-        # sample_every=2 keeps episodes 0 and 2; FL rounds land on the
-        # sampled-out episodes 1 and 3, so no fl spans at all
-        assert counts["episode"] == EPISODES // 2
-        assert counts["fl_round"] == 0
+    @pytest.mark.parametrize("kernel", ["queue_advance", "delta_codec"])
+    def test_kernel_wrappers_keep_their_jit_names(self, lowered_scans,
+                                                  kernel):
+        """A chip trace names a kernel's op after its jitted wrapper
+        (``_<kernel>_impl.<n>``); the benchmark's rooflines match it."""
+        assert kernel_ops.KERNEL_JITS[kernel].__name__ == f"_{kernel}_impl"
+        assert f"jit(_{kernel}_impl)" in lowered_scans("twin")
 
-    def test_kernel_spans_opt_in(self):
-        """Kernel wrappers emit only under an active kernel_spans tracer,
-        and the traced call returns the same values."""
-        from repro.kernels.ops import pack
-        tok = jnp.ones((16, 8), jnp.float32)
-        idx = jnp.asarray([0, 3, -1, 5], jnp.int32)
-        base = np.asarray(pack(tok, idx)[0])
-        with Tracer(kernel_spans=True) as tr, obs_trace.activate(tr):
-            out = np.asarray(pack(tok, idx)[0])
-        ev = tr.chrome_events()
-        assert [e["name"] for e in ev if e["ph"] == "X"] == ["kernel/pack"]
-        assert np.array_equal(base, out)
-        with Tracer(kernel_spans=False) as quiet, obs_trace.activate(quiet):
-            pack(tok, idx)
-        assert quiet.chrome_events() == []
+    def test_launcher_trace_out_writes_profiler_trace(self, tmp_path):
+        out = tmp_path / "prof"
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+                   PYTHONPATH=os.path.join(REPO, "src"))
+        proc = subprocess.run(
+            [sys.executable, "-m", "repro.launch.train_fleet", "--agents",
+             "2", "--pods", "1", "--episodes", "2", "--trace-out", str(out)],
+            cwd=str(tmp_path), env=env, capture_output=True, text=True,
+            timeout=600)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        names = [e["name"] for e in _profile_events(str(out))]
+        for span in HOST_SPANS:
+            assert names.count(span) == 1
+        assert glob.glob(str(out / "plugins" / "profile" / "*" /
+                             "perfetto_trace.json.gz"))
 
 
 class TestChromeTraceSchema:
     def test_export_roundtrip(self, tmp_path):
         tr = Tracer(pid=7)
-        with tr.span("compile", cat="host"):
-            with tr.span("lower", cat="host"):
-                pass
-        tr.instant("ckpt-written")
         tr.add_complete("req0/infer", ts_us=10.0, dur_us=5.0, pid=1000,
                         tid=2, args={"agent": 0})
+        tr.add_complete("req0/pre", ts_us=2.0, dur_us=8.0, tid=1)
         path = tr.export(str(tmp_path / "trace.json"))
-        tr.close()
         with open(path) as f:
             trace = json.load(f)
         assert validate_chrome_trace(trace) == []
         ev = trace["traceEvents"]
-        assert len(ev) == 4
-        names = {e["name"] for e in ev}
-        assert names == {"compile", "lower", "ckpt-written", "req0/infer"}
-        inner = next(e for e in ev if e["name"] == "lower")
-        outer = next(e for e in ev if e["name"] == "compile")
-        assert outer["ts"] <= inner["ts"]
-        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+        assert [e["name"] for e in ev] == ["req0/pre", "req0/infer"]
+        assert ev[0]["pid"] == 7 and ev[1]["pid"] == 1000
+        assert ev[1]["args"] == {"agent": 0}
+        assert (ev[1]["ts"], ev[1]["dur"]) == (10.0, 5.0)
 
     def test_validator_catches_malformed(self):
         assert validate_chrome_trace([1, 2]) != []
@@ -184,15 +206,6 @@ class TestChromeTraceSchema:
             "not-an-object",
         ):
             assert validate_chrome_trace({"traceEvents": [bad]}) != []
-
-    def test_interrupted_span_drains_as_instant(self):
-        tr = Tracer()
-        tr._begin("episode", "phase")  # begin with no matching end
-        trace = tr.chrome_trace()
-        tr.close()
-        assert validate_chrome_trace(trace) == []
-        (ev,) = trace["traceEvents"]
-        assert ev["ph"] == "i" and ev["cat"].endswith("-open")
 
 
 # ---------------------------------------------------------------------------
@@ -243,9 +256,9 @@ class TestRequestAttribution:
     def test_records_export_to_valid_chrome_slices(self, recorded_run):
         out = attribute_run(recorded_run["history"], recorded_run["state"],
                             sample_every=4)
-        with Tracer() as tr:
-            n = records_to_chrome(tr, out["records"], recorded_run["sp"].dt)
-            trace = tr.chrome_trace()
+        tr = Tracer()
+        n = records_to_chrome(tr, out["records"], recorded_run["sp"].dt)
+        trace = tr.chrome_trace()
         assert n > 0 and validate_chrome_trace(trace) == []
         assert sum(1 for e in trace["traceEvents"] if e["ph"] == "X") == n
 
