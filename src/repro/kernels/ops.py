@@ -15,6 +15,7 @@ profiler trace names the kernel's op after it (``_queue_advance_impl.<n>``).
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
@@ -129,14 +130,25 @@ def delta_codec(delta, residual, *, codec, k=1):
                      dict(codec=codec, k=k), agent_batched=delta.ndim == 2)
 
 
+@jax.custom_batching.custom_vmap
 def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps):
     """Fused request-level data-plane advance (digital twin): admit ->
     pre-process -> batch-form -> inference -> post-process -> deadline check,
-    K microticks per agent in one kernel call for the whole agent batch.
+    K microticks for a block of agents per grid step, one kernel call for
+    the whole agent batch. Operands are one agent's (``lat_sum`` a scalar)
+    or lead with the agent dim. Under ``vmap`` (the fleet's, over one
+    agent's call) the batching rule below makes it one call on the batch.
     Oracle: ``repro.kernels.ref.queue_advance_ref``."""
     return _dispatch(_QUEUE_ADVANCE,
                      (arrive, counters, credits, lat_sum, hist, arrivals,
-                      caps), {}, agent_batched=arrive.ndim == 2)
+                      caps), {}, agent_batched=lat_sum.ndim > 0)
+
+
+@queue_advance.def_vmap
+def _queue_advance_vmap(axis_size, in_batched, *args):
+    args = [x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, b in zip(args, in_batched)]
+    return queue_advance(*args), (True,) * 5
 
 
 # name -> jit wrapper — the profiler (repro.obs.profile) uses these to lower

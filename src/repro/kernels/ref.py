@@ -309,9 +309,10 @@ def delta_codec_ref(delta, residual, *, codec: str, k: int = 1):
 # membership is positional, a request's deadline is arrive + slo_ticks, and
 # ring slot ``i`` holds request number ``q`` iff q ≡ i (mod R) — so admission
 # and completion are mask writes/reads over ((i - ptr) & (R-1)) < n, never a
-# sort or a scatter. ``sim_microtick`` below is the single source of truth:
-# the jnp oracle (``queue_advance_ref``), the Pallas ``queue_advance`` kernel
-# body, and the harness all call it, so the implementations cannot drift.
+# sort or a scatter. ``sim_microtick_rows`` below is the single source of
+# truth: ``sim_microtick`` (one agent; the jnp oracle ``queue_advance_ref``
+# and the harness scan it) and the Pallas ``queue_advance`` kernel body (a
+# block of agents) both call it, so the implementations cannot drift.
 
 # counters vector layout (int32): five stage pointers (monotone request
 # counts), the inference-server occupancy flag + completion tick, four
@@ -333,13 +334,20 @@ def _iota(n):
     return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)[:, 0]
 
 
-def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):
-    """One microtick of the request-level pipeline, pure array ops.
+def sim_microtick_rows(arrive, c, credits, lat_sum, n_arrive, caps, hist_n):
+    """The math of one microtick, for one agent or for a block of agents.
 
-    arrive: (R,) int32 ring of arrival ticks; counters: (SIM_NCOUNTERS,)
-    int32; credits: (2,) float32 fractional pre/post service tokens;
-    lat_sum: () float32; hist: (H,) int32 completed-latency histogram in
-    ticks; n_arrive: () int32 arrivals this tick; caps: (SIM_NCAPS,) float32.
+    One agent: arrive is its (R,) ring and every per-agent value a scalar.
+    A block of B agents: arrive is (B, R), one ring per row, and every
+    per-agent value a (B, 1) column. The per-agent values are ``c`` (the
+    SIM_NCOUNTERS counters, a sequence), ``credits`` (2, a sequence),
+    ``lat_sum``, ``n_arrive`` (arrivals this tick) and ``caps`` (SIM_NCAPS,
+    a sequence). ``sim_microtick`` calls it for one agent and the Pallas
+    ``queue_advance`` kernel for a block, so the two share every operation.
+
+    Returns (arrive, c, credits, lat_sum, bucket): ``bucket`` is shaped
+    like ``arrive`` and holds, for each slot completed this tick, its
+    latency in ticks clipped to [0, hist_n - 1], and hist_n elsewhere.
 
     Stage order is a backward sweep (complete -> post -> launch -> pre ->
     admit) so a request spends >= 1 tick per stage; pre/post are token-bucket
@@ -352,14 +360,15 @@ def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):
     latency m + 1 - arrive ticks and counts as effective iff it is within
     slo_ticks. Python mirror: ``repro.sim.oracle`` (built on serving/slo.py).
     """
-    ring = arrive.shape[0]
+    ring = arrive.shape[-1]
     assert ring > 0 and ring & (ring - 1) == 0, \
         "ring capacity must be a positive power of two"
-    hist_n = hist.shape[0]
-    idx = _iota(ring)
-    c = counters
-    m = c[SIM_TICK]
+    idx = jax.lax.broadcasted_iota(jnp.int32, arrive.shape, arrive.ndim - 1)
 
+    def ring_sum(x):   # per agent: a scalar, or a (B, 1) column
+        return jnp.sum(x, axis=-1, keepdims=x.ndim > 1, dtype=jnp.int32)
+
+    m = c[SIM_TICK]
     c_pre, c_post = caps[CAP_PRE], caps[CAP_POST]
     batch_slots = caps[CAP_BATCH].astype(jnp.int32)
     t_batch = caps[CAP_TBATCH].astype(jnp.int32)
@@ -372,7 +381,7 @@ def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):
     busy = jnp.where(done, 0, c[SIM_BUSY])
 
     # (2) post-processing serves the n oldest post-queue requests; their
-    # latencies feed the accumulators and the histogram.
+    # latencies feed the accumulators and the histogram buckets.
     # (credits stay >= 0, so the int32 cast truncates == floor)
     post_credit = jnp.minimum(credits[1] + c_post, c_post + 1.0)
     n_post = jnp.minimum(post_credit.astype(jnp.int32),
@@ -380,12 +389,10 @@ def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):
     post_credit = post_credit - n_post.astype(jnp.float32)
     comp = ((idx - c[SIM_HEAD]) & (ring - 1)) < n_post
     lat = m + 1 - arrive
-    lat_sum = lat_sum + jnp.sum(jnp.where(comp, lat, 0)).astype(jnp.float32)
-    n_eff = jnp.sum(comp & (lat <= slo_ticks), dtype=jnp.int32)
+    lat_sum = lat_sum + ring_sum(jnp.where(comp, lat, 0)).astype(jnp.float32)
+    n_eff = ring_sum(comp & (lat <= slo_ticks))
     # non-completed slots bucket to the out-of-range sentinel hist_n
     bucket = jnp.where(comp, jnp.clip(lat, 0, hist_n - 1), hist_n)
-    hist = hist + jnp.sum(bucket[:, None] == _iota(hist_n)[None, :],
-                          axis=0, dtype=jnp.int32)
     head = c[SIM_HEAD] + n_post
 
     # (3) batch launch: work-conserving, backpressured by post-queue room
@@ -419,12 +426,30 @@ def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):
     arrive = jnp.where(adm, m, arrive)
     tail = c[SIM_TAIL] + admit
 
-    counters = jnp.stack([
-        tail, p_pre, launch, p_inf, head, busy, done_at,
-        c[SIM_ARRIVED] + n_arrive, c[SIM_DROPPED] + (n_arrive - admit),
-        c[SIM_COMPLETED] + n_post, c[SIM_EFFECTIVE] + n_eff, m + 1])
-    credits = jnp.stack([pre_credit, post_credit])
-    return arrive, counters, credits, lat_sum, hist
+    c = (tail, p_pre, launch, p_inf, head, busy, done_at,
+         c[SIM_ARRIVED] + n_arrive, c[SIM_DROPPED] + (n_arrive - admit),
+         c[SIM_COMPLETED] + n_post, c[SIM_EFFECTIVE] + n_eff, m + 1)
+    return arrive, c, (pre_credit, post_credit), lat_sum, bucket
+
+
+def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):
+    """One microtick of ONE agent's request-level pipeline, pure array ops
+    (the math is ``sim_microtick_rows``).
+
+    arrive: (R,) int32 ring of arrival ticks; counters: (SIM_NCOUNTERS,)
+    int32; credits: (2,) float32 fractional pre/post service tokens;
+    lat_sum: () float32; hist: (H,) int32 completed-latency histogram in
+    ticks, binned here every tick; n_arrive: () int32 arrivals this tick;
+    caps: (SIM_NCAPS,) float32. Returns the updated
+    (arrive, counters, credits, lat_sum, hist)."""
+    hist_n = hist.shape[0]
+    arrive, c, credits, lat_sum, bucket = sim_microtick_rows(
+        arrive, tuple(counters[j] for j in range(SIM_NCOUNTERS)),
+        (credits[0], credits[1]), lat_sum, n_arrive,
+        tuple(caps[j] for j in range(SIM_NCAPS)), hist_n)
+    hist = hist + jnp.sum(bucket[:, None] == _iota(hist_n)[None, :],
+                          axis=0, dtype=jnp.int32)
+    return arrive, jnp.stack(c), jnp.stack(credits), lat_sum, hist
 
 
 def queue_advance_ref(arrive, counters, credits, lat_sum, hist, arrivals,

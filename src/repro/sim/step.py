@@ -3,8 +3,8 @@
 ``sim_interval_ref`` is the single-agent jnp oracle (a ``lax.scan`` over the
 shared ``kernels.ref.sim_microtick``); ``sim_interval`` is the fleet-batched
 entry point that either vmaps the oracle or routes the whole agent batch
-through the fused Pallas ``queue_advance`` kernel — bit-identical paths
-(tests/test_sim.py).
+through the fused Pallas ``queue_advance`` kernel, which advances blocks of
+agents together — bit-identical paths (tests/test_sim.py).
 """
 from __future__ import annotations
 
@@ -28,8 +28,9 @@ def sim_interval_agent(state: SimState, arrivals: jnp.ndarray,
                        use_pallas: bool = False) -> SimState:
     """Advance ONE agent (the training-backend entry point — vmapped over
     the fleet by ``fleet_episode``): the jnp oracle scan, or the fused
-    Pallas kernel, which accepts unbatched operands and carries a batching
-    rule, so this call is legal under ``vmap`` on either path."""
+    Pallas kernel. ``kops.queue_advance`` accepts one agent's operands and
+    its batching rule turns the fleet's ``vmap`` of this call into one
+    kernel call on the whole (A, ...) batch."""
     if use_pallas:
         return SimState(*kops.queue_advance(*state, arrivals, caps))
     return sim_interval_ref(state, arrivals, caps)
@@ -38,8 +39,8 @@ def sim_interval_agent(state: SimState, arrivals: jnp.ndarray,
 def sim_interval(state: SimState, arrivals: jnp.ndarray, caps: jnp.ndarray,
                  use_pallas: bool = False) -> SimState:
     """Fleet-batched advance: state leaves (A, ...), arrivals (A, K), caps
-    (A, SIM_NCAPS). ``use_pallas`` fuses the whole interval per agent into
-    one kernel call for the batch."""
+    (A, SIM_NCAPS). ``use_pallas`` fuses the whole interval of every agent
+    into one kernel call for the batch."""
     if use_pallas:
         return SimState(*kops.queue_advance(*state, arrivals, caps))
     return jax.vmap(sim_interval_ref)(state, arrivals, caps)

@@ -23,3 +23,18 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip)
+
+
+def _grids(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["grid_mapping"].grid
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _grids(sub)
+
+
+@pytest.fixture
+def pallas_grids():
+    """fn(*args) -> the grid of every pallas_call its jaxpr holds,
+    sub-jaxprs included."""
+    return lambda fn, *args: list(_grids(jax.make_jaxpr(fn)(*args).jaxpr))

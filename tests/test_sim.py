@@ -1,6 +1,8 @@
 """Request-level twin: Pallas kernel vs jnp oracle bit-identity, twin vs the
 Python slo.py data plane (request-for-request), and the closed-loop
 ``simulate_fleet`` harness."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,8 @@ import pytest
 from repro.configs.fcpo import FCPOConfig
 from repro.core.fleet import fleet_init
 from repro.data.workload import fleet_traces
+from repro.kernels import ops as kops
+from repro.kernels import queue_advance as qa_mod
 from repro.kernels import ref as kref
 from repro.kernels.queue_advance import queue_advance
 from repro.sim import (SimParams, SimState, action_caps, hist_percentile,
@@ -36,10 +40,10 @@ def _random_args(a, key):
 class TestQueueAdvanceKernel:
     pytestmark = pytest.mark.pallas
 
-    def test_kernel_matches_oracle_batched_bit_identical(self):
+    @pytest.mark.parametrize("a", [1, 3, 13, 40])
+    def test_kernel_matches_oracle_batched_bit_identical(self, a):
         """Fused kernel (interpret mode on CPU) == vmap'd jnp oracle,
         bit-for-bit, chained over several control intervals."""
-        a = 4
         state = _batched_state(a)
         for i in range(5):
             arrivals, caps = _random_args(a, jax.random.fold_in(KEY, i))
@@ -48,25 +52,109 @@ class TestQueueAdvanceKernel:
             for name, p, r in zip(SimState._fields, out_pal, out_ref):
                 np.testing.assert_array_equal(np.asarray(p), np.asarray(r),
                                               err_msg=f"{name} @ interval {i}")
+            if a == 1:   # one agent's operands, without the agent dim
+                one = kops.queue_advance(*(x[0] for x in state),
+                                         arrivals[0], caps[0])
+                for name, p, r in zip(SimState._fields, one, out_ref):
+                    np.testing.assert_array_equal(np.asarray(p),
+                                                  np.asarray(r[0]),
+                                                  err_msg=name)
             state = SimState(*out_pal)
         assert int(state.completed.sum()) > 0  # the chain did real work
 
-    def test_kernel_bit_identical_under_vmap(self):
-        """vmap of the single-agent kernel call == the batched grid call ==
-        vmap of the oracle."""
-        a = 3
+    @pytest.mark.parametrize("a", [1, 3, 13, 40])
+    def test_kernel_bit_identical_under_vmap(self, a, pallas_grids):
+        """vmap of one agent's ``ops.queue_advance`` (the training route)
+        == the batched call == vmap of the oracle, and the batching rule
+        makes it one kernel call over blocks of agents, not one grid step
+        per agent."""
         state = _batched_state(a)
         arrivals, caps = _random_args(a, KEY)
         out_batch = queue_advance(*state, arrivals, caps, interpret=True)
-        out_vmap = jax.vmap(
-            lambda *xs: queue_advance(*xs, interpret=True))(*state, arrivals,
-                                                            caps)
+        out_vmap = jax.vmap(kops.queue_advance)(*state, arrivals, caps)
         out_ref = jax.vmap(kref.queue_advance_ref)(*state, arrivals, caps)
         for name, b, v, r in zip(SimState._fields, out_batch, out_vmap,
                                  out_ref):
             np.testing.assert_array_equal(np.asarray(b), np.asarray(v),
                                           err_msg=name)
             np.testing.assert_array_equal(np.asarray(b), np.asarray(r),
+                                          err_msg=name)
+        assert pallas_grids(jax.vmap(kops.queue_advance), *state, arrivals,
+                            caps) == [(1,)]
+
+    def test_kernel_pads_the_last_agent_block(self, monkeypatch,
+                                              pallas_grids):
+        """A fleet larger than one block runs as blocks of a multiple of 8
+        agents, the last one padded; the padding changes no agent."""
+        monkeypatch.setattr(qa_mod, "_VMEM_BUDGET", 8 * 4 * 256)
+        a = 13
+        assert qa_mod.agent_block(a, SP.ring, SP.hist_n, SP.k_ticks) == 8
+        state = _batched_state(a)
+        arrivals, caps = _random_args(a, KEY)
+        out_pal = queue_advance(*state, arrivals, caps, interpret=True)
+        out_ref = jax.vmap(kref.queue_advance_ref)(*state, arrivals, caps)
+        for name, p, r in zip(SimState._fields, out_pal, out_ref):
+            np.testing.assert_array_equal(np.asarray(p), np.asarray(r),
+                                          err_msg=name)
+        assert pallas_grids(functools.partial(queue_advance, interpret=True),
+                            *state, arrivals, caps) == [(2,)]
+
+    def test_kernel_bit_identical_at_bucket_edge_and_large_lat_sum(self):
+        """In-flight requests whose latencies straddle the last histogram
+        bucket, lat_sum past 2**24 (where float32 steps by 2, so a sum added
+        in another order or grouping would show), and a pipeline fast
+        enough that ring slots complete twice within one call (the
+        kernel's histogram then bins before the slot's second
+        completion)."""
+        a, ring, h = 13, SP.ring, SP.hist_n
+        state = _batched_state(a)
+        n0 = 10                    # requests waiting in the post queue
+        m0 = 40                    # the current microtick
+        lat0 = h - 4 + jnp.arange(ring) % 8     # latencies h-3 .. h+4
+        arrive = jnp.broadcast_to(m0 + 1 - lat0, (a, ring)).astype(jnp.int32)
+        counters = jnp.zeros((a, kref.SIM_NCOUNTERS), jnp.int32)
+        for j in (kref.SIM_TAIL, kref.SIM_PPRE, kref.SIM_LAUNCH,
+                  kref.SIM_PINF):
+            counters = counters.at[:, j].set(n0)
+        counters = counters.at[:, kref.SIM_TICK].set(m0)
+        lat_sum = 2.0 ** 24 + 2.0 * jnp.arange(a, dtype=jnp.float32)
+        state = state._replace(arrive=arrive, counters=counters,
+                               lat_sum=lat_sum)
+        arrivals = jnp.full((a, SP.k_ticks), 9, jnp.int32)
+        caps = jnp.broadcast_to(jnp.asarray([9.5, 9.5, 9.0, 1.0, 10.0, 30.0],
+                                            jnp.float32), (a, 6))
+        out_pal = queue_advance(*state, arrivals, caps, interpret=True)
+        out_ref = jax.vmap(kref.queue_advance_ref)(*state, arrivals, caps)
+        for name, p, r in zip(SimState._fields, out_pal, out_ref):
+            np.testing.assert_array_equal(np.asarray(p), np.asarray(r),
+                                          err_msg=name)
+        out = SimState(*out_ref)
+        assert (np.asarray(out.completed) > ring).all()   # slots reused
+        assert (np.asarray(out.hist)[:, h - 1] > 0).all()  # clipped edge
+        assert (np.asarray(out.lat_sum) > 2.0 ** 24).all()
+
+    def test_block_microtick_matches_per_agent(self):
+        """The block form of the microtick (agents on rows, per-agent
+        values as (B, 1) columns) == ``sim_microtick`` vmapped over the
+        agents, for every output of the tick."""
+        a = 5
+        state = _batched_state(a)
+        for i in range(4):
+            arrivals, caps = _random_args(a, jax.random.fold_in(KEY, i))
+            state = SimState(*jax.vmap(kref.queue_advance_ref)(
+                *state, arrivals, caps))
+        n_arr = arrivals[:, :1]
+        col = lambda x: tuple(x[:, j:j + 1] for j in range(x.shape[1]))
+        arrive, c, credits, lat_sum, bucket = kref.sim_microtick_rows(
+            state.arrive, col(state.counters), col(state.credits),
+            state.lat_sum[:, None], n_arr, col(caps), SP.hist_n)
+        per = jax.vmap(kref.sim_microtick)(*state, n_arr[:, 0], caps)
+        hist = state.hist + (bucket[:, :, None]
+                             == jnp.arange(SP.hist_n)).sum(1)
+        got = (arrive, jnp.concatenate(c, 1), jnp.concatenate(credits, 1),
+               lat_sum[:, 0], hist)
+        for name, g, p in zip(SimState._fields, got, per):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(p),
                                           err_msg=name)
 
 

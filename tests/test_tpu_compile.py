@@ -67,22 +67,49 @@ def shapes(sharding, *specs):
     return [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in specs]
 
 
+def _twin_avals(sharding, a=A):
+    return shapes(sharding, ((a, RING), I32), ((a, kref.SIM_NCOUNTERS), I32),
+                  ((a, 2), F32), ((a,), F32), ((a, HIST), I32), ((a, K), I32),
+                  ((a, kref.SIM_NCAPS), F32))
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The ops dispatch asks the (CPU) backend whether to interpret:
+    steer it to compile, and drop traces the interpreter made."""
+    monkeypatch.setattr(kops, "_interpret_default", lambda: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
 def test_queue_advance_compiles(one_chip):
-    avals = shapes(one_chip, ((A, RING), I32), ((A, kref.SIM_NCOUNTERS), I32),
-                   ((A, 2), F32), ((A,), F32), ((A, HIST), I32),
-                   ((A, K), I32), ((A, kref.SIM_NCAPS), F32))
-    txt = compiled_text(qa_mod.queue_advance, *avals)
+    txt = compiled_text(qa_mod.queue_advance, *_twin_avals(one_chip))
     assert "tpu_custom_call" in txt
 
 
-def test_queue_advance_per_agent_under_vmap_compiles(one_chip):
+def test_queue_advance_per_agent_under_vmap_compiles(one_chip,
+                                                     compiled_kernels):
     """The twin backend's call: one agent's operands, batched by the
-    fleet's vmap into an extra grid dimension."""
-    avals = shapes(one_chip, ((A, RING), I32), ((A, kref.SIM_NCOUNTERS), I32),
-                   ((A, 2), F32), ((A,), F32), ((A, HIST), I32),
-                   ((A, K), I32), ((A, kref.SIM_NCAPS), F32))
-    txt = compiled_text(jax.vmap(qa_mod.queue_advance), *avals)
+    fleet's vmap into one call on the whole batch."""
+    txt = compiled_text(jax.vmap(kops.queue_advance), *_twin_avals(one_chip))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("a", [40, A])
+def test_queue_advance_vmap_is_one_call_over_agent_blocks(one_chip,
+                                                          compiled_kernels,
+                                                          pallas_grids, a):
+    """Under the fleet's vmap the twin is one Mosaic call whose grid walks
+    blocks of agents (one block for the 40-camera cell), never one grid
+    step per agent."""
+    avals = _twin_avals(one_chip, a)
+    fn = jax.vmap(kops.queue_advance)
+    blk = qa_mod.agent_block(a, RING, HIST, K)
+    assert pallas_grids(fn, *avals) == [(-(-a // blk),)]
+    assert (a == 40) == (blk == a)
+    txt = compiled_text(fn, *avals)
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("codec", kref.DELTA_CODECS)
@@ -119,22 +146,29 @@ def test_pack_compiles(one_chip):
     assert "tpu_custom_call" in compiled_text(pack_mod.pack, *avals)
 
 
-def test_fleet_kernel_runs_per_device_under_a_mesh(topo, monkeypatch):
+def test_fleet_kernel_runs_per_device_under_a_mesh(topo, compiled_kernels):
     """Mosaic kernels cannot be partitioned by SPMD: under a mesh the ops
     dispatch runs them in shard_map, each chip on its own agents."""
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("pod", "data"),
                 axis_types=(AxisType.Auto,) * 2)
     agents = NamedSharding(mesh, P(("pod", "data")))
     avals = shapes(agents, ((A, PARAMS_L), F32), ((A, PARAMS_L), F32))
-    # the dispatch asks the (CPU) backend whether to interpret: steer it,
-    # and drop traces the interpreter made
-    monkeypatch.setattr(kops, "_interpret_default", lambda: False)
-    jax.clear_caches()
-    try:
-        with jax.set_mesh(mesh):
-            txt = compiled_text(lambda d, r: kops.delta_codec(
-                d, r, codec="int8"), *avals)
-    finally:
-        jax.clear_caches()
+    with jax.set_mesh(mesh):
+        txt = compiled_text(lambda d, r: kops.delta_codec(
+            d, r, codec="int8"), *avals)
     assert "tpu_custom_call" in txt
     assert f"f32[{A // 4},1,{PARAMS_L}]" in txt   # a quarter per chip
+
+
+def test_twin_kernel_runs_per_device_under_a_mesh(topo, compiled_kernels):
+    """The fleet's vmap over one agent's twin call, with its agent axis
+    named on the mesh: each chip runs the kernel once on its own quarter
+    of the agents."""
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("pod", "data"),
+                axis_types=(AxisType.Auto,) * 2)
+    avals = _twin_avals(NamedSharding(mesh, P(("pod", "data"))))
+    fn = jax.vmap(kops.queue_advance, spmd_axis_name=("pod", "data"))
+    with jax.set_mesh(mesh):
+        txt = compiled_text(fn, *avals)
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"s32[{A // 4},{RING}]" in txt   # a quarter per chip
