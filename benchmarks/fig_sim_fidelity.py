@@ -111,9 +111,9 @@ def run_fidelity(n_agents=8, train_episodes=40, eval_intervals=40, seed=0):
                           n_eps * cfg.n_steps)
     _, hist_fluid = train_fleet(cfg, fleet, traces, learn=False,
                                 federated=False)
-    _, _, summ = simulate_fleet(cfg, sp, fleet.astate.params, fleet.masks,
-                                fleet.env_params, traces,
-                                jax.random.PRNGKey(seed + 3))
+    summ = simulate_fleet(cfg, sp, fleet.astate.params, fleet.masks,
+                          fleet.env_params, traces,
+                          jax.random.PRNGKey(seed + 3)).summary
     eff_fluid = float(np.mean(hist_fluid["effective_throughput"]))
     eff_twin = float(np.asarray(summ["effective_throughput"]).mean())
     thr_fluid = float(np.mean(hist_fluid["throughput"]))

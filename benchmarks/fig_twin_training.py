@@ -66,9 +66,10 @@ def run_ab(scenarios=AB_SCENARIOS, n_agents=8, train_episodes=60,
             train_s = time.perf_counter() - t0
             effs, p99s, drops = [], [], []
             for j, ev in enumerate(held_out):
-                _, _, summ = simulate_fleet(
+                summ = simulate_fleet(
                     cfg, sp, fleet.astate.params, fleet.masks,
-                    fleet.env_params, ev, jax.random.PRNGKey(seed + 3 + j))
+                    fleet.env_params, ev,
+                    jax.random.PRNGKey(seed + 3 + j)).summary
                 effs.append(
                     float(np.asarray(summ["effective_throughput"]).mean()))
                 p99s.append(float(np.asarray(summ["p99_latency_s"]).mean()))

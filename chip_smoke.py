@@ -175,10 +175,10 @@ def phase_twin(cfg, sp, fleet):
     out = {}
     for use_pallas in (True, False):
         t = time.perf_counter()
-        state, _, _ = simulate_fleet(cfg, sp, fleet.astate.params,
-                                     fleet.masks, fleet.env_params, traces,
-                                     key, use_pallas=use_pallas)
-        state = jax.device_get(state)
+        run = simulate_fleet(cfg, sp, fleet.astate.params, fleet.masks,
+                             fleet.env_params, traces, key,
+                             use_pallas=use_pallas)
+        state = jax.device_get(run.state)
         name = "kernel" if use_pallas else "oracle"
         print(f"phase 2 simulate_fleet ({name}): "
               f"{time.perf_counter() - t:.1f} s", flush=True)

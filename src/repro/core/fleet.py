@@ -214,6 +214,13 @@ def fleet_device_bytes(fleet: Fleet) -> Dict[int, float]:
     return per
 
 
+def device_speeds(n_agents: int) -> jnp.ndarray:
+    """The default heterogeneous device mix (Orin/NX/AGX/server-like):
+    per-agent speed factors, the same for every fleet of ``n_agents``."""
+    return jnp.asarray(
+        np.random.default_rng(0).choice([0.5, 0.75, 1.0, 2.0], n_agents))
+
+
 def fleet_init(cfg: FCPOConfig, n_agents: int, key, *, n_pods: int = 1,
                masks: Optional[ActionMask] = None,
                speeds: Optional[jnp.ndarray] = None,
@@ -240,9 +247,8 @@ def fleet_init(cfg: FCPOConfig, n_agents: int, key, *, n_pods: int = 1,
     env_states = jax.vmap(lambda _: backend.init(cfg))(jnp.arange(n_agents))
     rngs = jax.random.split(kr, n_agents)
 
-    if speeds is None:  # heterogeneous device mix (Orin/NX/AGX/server-like)
-        speeds = jnp.asarray(
-            np.random.default_rng(0).choice([0.5, 0.75, 1.0, 2.0], n_agents))
+    if speeds is None:
+        speeds = device_speeds(n_agents)
     if bandwidth is None:
         bandwidth = jnp.asarray(
             np.random.default_rng(1).uniform(2.0, 40.0, n_agents))

@@ -58,6 +58,35 @@ def fleet_traces(key, n_agents: int, n_steps: int, base_rate: float = 30.0,
     return jax.vmap(lambda k, b: make_trace(k, n_steps, b, **trace_kw))(keys, bases)
 
 
+# objects per frame move in steps of 2**-8, so that a pipeline stage's
+# fan-out spread is exact in float32 (``sim.state.fanout_arrivals``)
+OBJECT_STEP = 1.0 / 256
+
+
+def object_counts(key, n_cameras: int, n_steps: int, base: float = 2.0,
+                  heterogeneity: float = 0.5, wander: float = 0.4,
+                  burst_prob: float = 0.05, burst_scale: float = 3.0,
+                  max_objects: float = 12.0):
+    """(C, n_steps) objects per frame of each camera, per control interval
+    (what a detector hands each downstream model): a per-camera base spread
+    by ``heterogeneity`` around ``base``, an AR(1) wander of scale
+    ``wander``, independent ``burst_scale`` bursts in ``burst_prob`` of the
+    intervals, clipped to [0, max_objects] and rounded to ``OBJECT_STEP``."""
+    kb, kt = jax.random.split(key)
+    bases = base * (1.0 + heterogeneity * (
+        jax.random.uniform(kb, (n_cameras,)) * 2 - 1))
+
+    def one(k, b):
+        k1, k2 = jax.random.split(k)
+        noise = 1.0 + smooth_noise(k1, n_steps, scale=wander)
+        bursts = jnp.where(jax.random.uniform(k2, (n_steps,)) < burst_prob,
+                           burst_scale, 1.0)
+        return jnp.clip(b * noise * bursts, 0.0, max_objects)
+
+    f = jax.vmap(one)(jax.random.split(kt, n_cameras), bases)
+    return jnp.round(f / OBJECT_STEP) * OBJECT_STEP
+
+
 # Fig. 2a-grade content dynamics (3-10x swings): used by the fig7/9/10
 # benchmarks so runtime conditions genuinely differ from profiling data.
 DYNAMIC = dict(regime_scale=0.9, burst_prob=0.05, burst_scale=4.0)

@@ -150,9 +150,9 @@ def evaluate_cell(cfg: FCPOConfig, fleet: Fleet, cell: Cell, *,
                            jax.random.PRNGKey(cell_seed(seed, cell, r,
                                                         "eval")),
                            a, eval_intervals)
-        _, _, summ = eval_fleet(cfg, eval_sp, f, ev,
-                                jax.random.PRNGKey(cell_seed(seed, cell, r,
-                                                             "key")))
+        summ = eval_fleet(cfg, eval_sp, f, ev,
+                          jax.random.PRNGKey(cell_seed(seed, cell, r,
+                                                       "key"))).summary
         reps["eval_eff"].append(
             float(np.asarray(summ["effective_throughput"]).mean()))
         reps["eval_p99"].append(

@@ -68,6 +68,13 @@ def _queue_advance_impl(arrive, counters, credits, lat_sum, hist, arrivals,
                              arrivals, caps, interpret=_interpret_default())
 
 
+def _pipeline_advance_impl(arrive, counters, credits, lat_sum, hist,
+                           arrivals, caps, objects, phase, *, stages):
+    return _qa.pipeline_advance(arrive, counters, credits, lat_sum, hist,
+                                arrivals, caps, objects, phase, stages=stages,
+                                interpret=_interpret_default())
+
+
 _FLASH = jax.jit(_flash_impl, static_argnames=("causal", "bq", "bk"))
 _DECODE = jax.jit(_decode_impl, static_argnames=("bk",))
 _PACK = jax.jit(_pack_impl)
@@ -75,6 +82,8 @@ _DIVERSITY = jax.jit(_diversity_impl, static_argnames=("alpha", "beta",
                                                        "ridge"))
 _DELTA_CODEC = jax.jit(_delta_codec_impl, static_argnames=("codec", "k"))
 _QUEUE_ADVANCE = jax.jit(_queue_advance_impl)
+_PIPELINE_ADVANCE = jax.jit(_pipeline_advance_impl,
+                            static_argnames=("stages",))
 
 
 def _dispatch(kernel, args, kw, agent_batched=None):
@@ -151,6 +160,17 @@ def _queue_advance_vmap(axis_size, in_batched, *args):
     return queue_advance(*args), (True,) * 5
 
 
+def pipeline_advance(arrive, counters, credits, lat_sum, hist, arrivals,
+                     caps, objects, phase, *, stages):
+    """Every stage of a pipeline fleet through one control interval in one
+    kernel call, each later stage fed the fan-out of the completions one
+    stage up in the tick they happen. Operands lead with the stage-major
+    agents. Oracle: ``sim/harness``'s stage-by-stage jnp route
+    (``sim_interval_recorded`` and ``sim.state.fanout_arrivals``)."""
+    return _PIPELINE_ADVANCE(arrive, counters, credits, lat_sum, hist,
+                             arrivals, caps, objects, phase, stages=stages)
+
+
 # name -> jit wrapper — the profiler (repro.obs.profile) uses these to lower
 # and cost/memory-account every kernel variant; they are the exact objects
 # the dispatchers call, so the analyzed program is the one that runs.
@@ -161,4 +181,5 @@ KERNEL_JITS = {
     "diversity_insert": _DIVERSITY,
     "delta_codec": _DELTA_CODEC,
     "queue_advance": _QUEUE_ADVANCE,
+    "pipeline_advance": _PIPELINE_ADVANCE,
 }

@@ -40,12 +40,13 @@ from repro.kernels import ref as kref
 _VMEM_BUDGET = 1 << 20
 
 
-def agent_block(n_agents, ring, hist_n, k_ticks):
+def agent_block(n_agents, ring, hist_n, k_ticks, rows_per_agent=1):
     """Agents per grid step: all of them when their operands fit
     ``_VMEM_BUDGET``, else the fewest near-equal blocks that fit, rounded
-    up to a multiple of 8 (Mosaic's sublane tile)."""
+    up to a multiple of 8 (Mosaic's sublane tile). ``rows_per_agent``:
+    the rows each takes (a pipeline's camera: one per stage)."""
     words = (2 * (ring + kref.SIM_NCOUNTERS + 2 + 1 + hist_n) + k_ticks
-             + kref.SIM_NCAPS)
+             + kref.SIM_NCAPS) * rows_per_agent
     most = max(8, _VMEM_BUDGET // (4 * words) // 8 * 8)
     if n_agents <= most:
         return n_agents
@@ -155,4 +156,105 @@ def queue_advance(arrive, counters, credits, lat_sum, hist, arrivals, caps,
     )(*ops)
     out = [x.reshape(lead + x.shape[1:]) for x in out]
     out[3] = out[3][..., 0]
+    return tuple(out)
+
+
+def _pipeline_kernel(arrive_ref, counters_ref, credits_ref, latsum_ref,
+                     hist_ref, arrivals_ref, caps_ref, fan_ref,
+                     o_arrive, o_counters, o_credits, o_latsum, o_hist,
+                     o_phase):
+    stages, blk = arrive_ref.shape[:2]
+    rows = stages * blk
+    flat = lambda ref: ref[...].reshape(rows, ref.shape[2])
+    hist_n = hist_ref.shape[2]
+    caps = tuple(flat(caps_ref)[:, j:j + 1] for j in range(kref.SIM_NCAPS))
+    arrivals = flat(arrivals_ref)
+    fan = flat(fan_ref)
+    objects, phase = fan[:, 0:1], fan[:, 1:2]
+    tick_ids = jax.lax.broadcasted_iota(jnp.int32, arrivals.shape, 1)
+    first = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < blk
+    none = jnp.full((rows, arrive_ref.shape[2]), hist_n, jnp.int32)
+
+    def tick(t, carry):
+        arrive, c, credits, lat_sum, hist, slots, sent, floor_prev = carry
+        c, credits, lat_sum, bucket, n_post = kref.sim_serve_rows(
+            arrive, c, credits, lat_sum, caps, hist_n)
+        # a later stage's row takes the completions of the row one stage
+        # up (the same camera), fanned out, in the same tick
+        sent = sent + jnp.where(first, 0, jnp.roll(n_post, blk, axis=0))
+        floors = jnp.floor(phase + objects * sent.astype(jnp.float32))
+        n_arr = jnp.where(
+            first, jnp.sum(jnp.where(tick_ids == t, arrivals, 0), axis=1,
+                           keepdims=True),
+            (floors - floor_prev).astype(jnp.int32))
+        arrive, c = kref.sim_admit_rows(arrive, c, n_arr, caps)
+        done = bucket < hist_n
+        again = jnp.max(jnp.where(done & (slots < hist_n), 1, 0)) > 0
+        hist, slots = jax.lax.cond(again, lambda: (_bin(hist, slots), none),
+                                   lambda: (hist, slots))
+        slots = jnp.where(done, bucket, slots)
+        return arrive, c, credits, lat_sum, hist, slots, sent, floors
+
+    col = lambda v: tuple(v[:, j:j + 1] for j in range(v.shape[1]))
+    carry = (flat(arrive_ref), col(flat(counters_ref)),
+             col(flat(credits_ref)), flat(latsum_ref), flat(hist_ref), none,
+             jnp.zeros((rows, 1), jnp.int32), jnp.floor(phase))
+    arrive, c, credits, lat_sum, hist, slots, sent, _ = jax.lax.fori_loop(
+        0, arrivals.shape[1], tick, carry)
+    cum = phase + objects * sent.astype(jnp.float32)
+    back = lambda v, ref: v.reshape(ref.shape)
+    o_arrive[...] = back(arrive, o_arrive)
+    o_counters[...] = back(_tile(c), o_counters)
+    o_credits[...] = back(_tile(credits), o_credits)
+    o_latsum[...] = back(lat_sum, o_latsum)
+    o_hist[...] = back(_bin(hist, slots), o_hist)
+    o_phase[...] = back(jnp.where(first, phase, cum - jnp.floor(cum)),
+                        o_phase)
+
+
+def pipeline_advance(arrive, counters, credits, lat_sum, hist, arrivals,
+                     caps, objects, phase, *, stages, interpret=False):
+    """Every stage of a pipeline fleet through one control interval in ONE
+    call: per microtick, every agent's service steps, then each later
+    stage's fan-out of the completions one stage up (``objects`` per
+    completion, ``phase`` carried), then every agent's admission — the
+    arrivals of ``fanout_arrivals``, entering in the tick they were sent.
+
+    Operands lead with the A = stages x cameras agents, stage-major, as in
+    ``queue_advance``; ``arrivals`` are stage 0's (the other rows' are
+    ignored); objects, phase: (A,) float32 (stage 0's phase passes through
+    unchanged). Returns (arrive, counters, credits, lat_sum, hist, phase).
+    A grid step takes whole cameras: the block of cameras of every
+    stage."""
+    a, ring = arrive.shape
+    cams = a // stages
+    k_ticks, hist_n = arrivals.shape[-1], hist.shape[-1]
+    f32, i32 = jnp.float32, jnp.int32
+    widths = (ring, kref.SIM_NCOUNTERS, 2, 1, hist_n, k_ticks,
+              kref.SIM_NCAPS, 2)
+    dtypes = (i32, i32, f32, f32, i32, i32, f32, f32)
+    ops = [x.astype(d).reshape(stages, cams, w) for x, d, w in zip(
+        (arrive, counters, credits, lat_sum, hist, arrivals, caps,
+         jnp.stack([objects, phase], axis=-1)), dtypes, widths)]
+    # a camera is a row per stage; the objects, phase in and phase out are
+    # three words more than a single model's operands
+    blk = agent_block(cams, ring, hist_n, k_ticks + 3, rows_per_agent=stages)
+    specs = [pl.BlockSpec((stages, blk, w), lambda i: (0, i, 0))
+             for w in widths]
+    out_widths = widths[:5] + (1,)
+    out = pl.pallas_call(
+        _pipeline_kernel,
+        grid=(pl.cdiv(cams, blk),),
+        in_specs=specs,
+        out_specs=specs[:5] + [pl.BlockSpec((stages, blk, 1),
+                                            lambda i: (0, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((stages, cams, w), d)
+                   for w, d in zip(out_widths, dtypes[:5] + (f32,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(*ops)
+    out = [x.reshape(a, x.shape[2]) for x in out]
+    out[3] = out[3][:, 0]
+    out[5] = out[5][:, 0]
     return tuple(out)

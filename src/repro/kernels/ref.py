@@ -360,10 +360,26 @@ def sim_microtick_rows(arrive, c, credits, lat_sum, n_arrive, caps, hist_n):
     latency m + 1 - arrive ticks and counts as effective iff it is within
     slo_ticks. Python mirror: ``repro.sim.oracle`` (built on serving/slo.py).
     """
+    c, credits, lat_sum, bucket, _ = sim_serve_rows(arrive, c, credits,
+                                                    lat_sum, caps, hist_n)
+    arrive, c = sim_admit_rows(arrive, c, n_arrive, caps)
+    return arrive, c, credits, lat_sum, bucket
+
+
+def _ring_idx(arrive):
     ring = arrive.shape[-1]
     assert ring > 0 and ring & (ring - 1) == 0, \
         "ring capacity must be a positive power of two"
-    idx = jax.lax.broadcasted_iota(jnp.int32, arrive.shape, arrive.ndim - 1)
+    return jax.lax.broadcasted_iota(jnp.int32, arrive.shape, arrive.ndim - 1)
+
+
+def sim_serve_rows(arrive, c, credits, lat_sum, caps, hist_n):
+    """Steps (1)-(4) of ``sim_microtick_rows``: everything but admission.
+    Returns (c, credits, lat_sum, bucket, n_post): ``c`` with the service
+    pointers and the completion accumulators advanced, and ``n_post`` the
+    requests completed this tick (a per-agent value)."""
+    ring = arrive.shape[-1]
+    idx = _ring_idx(arrive)
 
     def ring_sum(x):   # per agent: a scalar, or a (B, 1) column
         return jnp.sum(x, axis=-1, keepdims=x.ndim > 1, dtype=jnp.int32)
@@ -417,19 +433,31 @@ def sim_microtick_rows(arrive, c, credits, lat_sum, n_arrive, caps, hist_n):
     pre_credit = pre_credit - n_pre.astype(jnp.float32)
     p_pre = c[SIM_PPRE] + n_pre
 
-    # (5) admission into the bounded pre queue; overflow drops. Each stage
-    # queue is <= qcap, so with ring >= 3*qcap the ring bound never binds.
-    free = jnp.minimum(qcap - (c[SIM_TAIL] - p_pre),
-                       ring - (c[SIM_TAIL] - head))
+    c = (c[SIM_TAIL], p_pre, launch, p_inf, head, busy, done_at,
+         c[SIM_ARRIVED], c[SIM_DROPPED], c[SIM_COMPLETED] + n_post,
+         c[SIM_EFFECTIVE] + n_eff, m)
+    return c, (pre_credit, post_credit), lat_sum, bucket, n_post
+
+
+def sim_admit_rows(arrive, c, n_arrive, caps):
+    """Step (5) of ``sim_microtick_rows``, after ``sim_serve_rows``:
+    admission into the bounded pre queue, overflow dropped, and the tick
+    clock advanced. Returns (arrive, c)."""
+    ring = arrive.shape[-1]
+    idx = _ring_idx(arrive)
+    m = c[SIM_TICK]
+    qcap = caps[CAP_QCAP].astype(jnp.int32)
+    # Each stage queue is <= qcap, so with ring >= 3*qcap the ring bound
+    # never binds.
+    free = jnp.minimum(qcap - (c[SIM_TAIL] - c[SIM_PPRE]),
+                       ring - (c[SIM_TAIL] - c[SIM_HEAD]))
     admit = jnp.clip(jnp.minimum(n_arrive, free), 0, n_arrive)
     adm = ((idx - c[SIM_TAIL]) & (ring - 1)) < admit
     arrive = jnp.where(adm, m, arrive)
-    tail = c[SIM_TAIL] + admit
-
-    c = (tail, p_pre, launch, p_inf, head, busy, done_at,
-         c[SIM_ARRIVED] + n_arrive, c[SIM_DROPPED] + (n_arrive - admit),
-         c[SIM_COMPLETED] + n_post, c[SIM_EFFECTIVE] + n_eff, m + 1)
-    return arrive, c, (pre_credit, post_credit), lat_sum, bucket
+    c = (c[SIM_TAIL] + admit,) + tuple(c[1:SIM_ARRIVED]) + (
+        c[SIM_ARRIVED] + n_arrive, c[SIM_DROPPED] + (n_arrive - admit),
+        c[SIM_COMPLETED], c[SIM_EFFECTIVE], m + 1)
+    return arrive, c
 
 
 def sim_microtick(arrive, counters, credits, lat_sum, hist, n_arrive, caps):

@@ -7,11 +7,19 @@ throughput, p50/p99 end-to-end latency, and drops. ``--compare-fluid``
 additionally evaluates the same policies on the fluid ``core/env.py`` MDP
 over the same traces and prints the fidelity gap.
 
+``--stages 2`` evaluates pipelines: every camera runs a detector on each
+frame and a second model (re-ID) on every object it finds, one iAgent per
+model, coupled through the twin's fan-out (``repro.sim.Pipeline``); the
+objects per frame come from ``data.workload.object_counts``. Each stage
+takes its own speed factor and SLO (``--stage-speed``, ``--stage-slo``).
+
 Examples:
   PYTHONPATH=src python -m repro.launch.simulate --agents 8 --intervals 60
   PYTHONPATH=src python -m repro.launch.simulate --agents 16 --scenario ood \
       --train-episodes 40 --compare-fluid
   PYTHONPATH=src python -m repro.launch.simulate --agents 4 --pallas
+  PYTHONPATH=src python -m repro.launch.simulate --agents 40 --stages 2 \
+      --stage-speed 1,4 --stage-slo 0.15,0.10 --scenario burst --pallas
 """
 from __future__ import annotations
 
@@ -23,15 +31,33 @@ import numpy as np
 
 from repro.configs.fcpo import FCPOConfig
 from repro.core.backends import BACKENDS, get_backend
-from repro.core.fleet import fleet_init, train_fleet
-from repro.data.workload import fleet_traces
+from repro.core.fleet import device_speeds, fleet_init, train_fleet
+from repro.data.workload import fleet_traces, object_counts
 from repro.launch import compile_cache
-from repro.sim import SCENARIOS, SimParams, make_scenario, simulate_fleet
+from repro.sim import (SCENARIOS, Pipeline, SimParams, make_scenario,
+                       pipeline_env_params, simulate_fleet)
+
+
+def _floats(text):
+    return [float(x) for x in text.split(",")]
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--agents", type=int, default=8)
+    ap.add_argument("--agents", type=int, default=8,
+                    help="cameras; each runs --stages models, one iAgent "
+                         "per model")
+    ap.add_argument("--stages", type=int, default=1,
+                    help="models chained per camera (2 = detector -> "
+                         "re-ID); a stage after the first gets its "
+                         "upstream's completions times the objects per "
+                         "frame")
+    ap.add_argument("--stage-speed", type=_floats, metavar="F,F,...",
+                    help="per-stage device speed factor, times the "
+                         "camera's device (default: 1 for every stage)")
+    ap.add_argument("--stage-slo", type=_floats, metavar="S,S,...",
+                    help="per-stage deadline (s) from the stage's own "
+                         "arrival (default: the SLO split evenly)")
     ap.add_argument("--intervals", type=int, default=60,
                     help="control intervals to simulate")
     ap.add_argument("--scenario", choices=SCENARIOS, default="dynamic")
@@ -77,8 +103,22 @@ def main(argv=None):
     if args.attribution and args.pallas:
         ap.error("--attribution needs the jnp data plane (drop --pallas): "
                  "the fused kernel advances whole intervals per call")
+    if args.stages < 1:
+        ap.error("--stages must be >= 1")
+    for name in ("stage_speed", "stage_slo"):
+        val = getattr(args, name)
+        if val is not None and len(val) != args.stages:
+            ap.error(f"--{name.replace('_', '-')} needs one value per stage "
+                     f"({args.stages}), got {len(val)}")
+    if args.stages > 1 and args.train_episodes > 0:
+        ap.error("--stages > 1 evaluates untrained policies: training "
+                 "through coupled stages is not supported yet")
+    if args.stages > 1 and args.compare_fluid:
+        ap.error("--compare-fluid has no pipeline counterpart in the fluid "
+                 "MDP; drop it with --stages > 1")
 
     cfg = FCPOConfig()
+    pipe = Pipeline(args.agents, args.stages)
     if args.compare_fluid and args.intervals % cfg.n_steps:
         # the fluid plane evaluates in whole episodes; keep both planes on
         # the identical workload window
@@ -89,27 +129,35 @@ def main(argv=None):
                    hist_n=args.hist)
     train_be = get_backend(args.train_backend, sim_params=sp,
                            use_pallas=args.pallas)
-    fleet = fleet_init(cfg, args.agents, jax.random.PRNGKey(args.seed),
+    fleet = fleet_init(cfg, pipe.agents, jax.random.PRNGKey(args.seed),
                        env_backend=train_be)
     if args.train_episodes > 0:
         warmup = fleet_traces(jax.random.PRNGKey(args.seed + 1), args.agents,
                               args.train_episodes * cfg.n_steps)
         fleet, _ = train_fleet(cfg, fleet, warmup, env_backend=train_be)
+    objects = None
+    if args.stages > 1 or args.stage_speed or args.stage_slo:
+        fleet = fleet._replace(env_params=pipeline_env_params(
+            pipe, device_speeds(args.agents),
+            args.stage_speed or [1.0] * args.stages,
+            args.stage_slo or [cfg.slo_s / args.stages] * args.stages))
+    if args.stages > 1:
+        objects = object_counts(jax.random.PRNGKey(args.seed + 4),
+                                args.agents, args.intervals)
     traces = make_scenario(args.scenario, jax.random.PRNGKey(args.seed + 2),
                            args.agents, args.intervals)
 
-    print(f"twin: {args.agents} agents, {args.intervals} intervals, "
+    print(f"twin: {args.agents} cameras x {args.stages} stage(s), "
+          f"{args.intervals} intervals, "
           f"K={sp.k_ticks} microticks of {sp.dt * 1e3:.0f} ms, "
           f"ring={sp.ring}, scenario={args.scenario}, "
           f"pallas={args.pallas}, trained={args.train_episodes} eps "
           f"on {train_be.name}, backend={jax.default_backend()}")
     t0 = time.time()
-    state, history, summ = simulate_fleet(cfg, sp, fleet.astate.params,
-                                          fleet.masks, fleet.env_params,
-                                          traces,
-                                          jax.random.PRNGKey(args.seed + 3),
-                                          use_pallas=args.pallas,
-                                          record_ticks=args.attribution)
+    state, history, summ, _ = simulate_fleet(
+        cfg, sp, fleet.astate.params, fleet.masks, fleet.env_params, traces,
+        jax.random.PRNGKey(args.seed + 3), use_pallas=args.pallas,
+        record_ticks=args.attribution, pipeline=pipe, objects=objects)
     jax.block_until_ready(state.counters)
     wall = time.time() - t0
     ticks = args.intervals * sp.k_ticks
@@ -127,6 +175,8 @@ def main(argv=None):
     print(f"{'requests':24s}arrived={int(np.asarray(summ['arrived']).sum())} "
           f"completed={int(np.asarray(summ['completed']).sum())} "
           f"dropped={int(np.asarray(summ['dropped']).sum())}")
+    if args.stages > 1:
+        print(stage_table(pipe, summ, history))
     # >1% right-censored completions triggers warn_if_censored inside
     # simulate_fleet (one shared check); the hist_censored row above is the
     # always-on surface.
@@ -161,6 +211,26 @@ def main(argv=None):
         print(f"\nfluid-vs-twin effective throughput: fluid={eff_f:.2f} "
               f"twin={eff_t:.2f} gap={gap * 100:.1f}%")
     return summ
+
+
+def stage_table(pipe, summ, history):
+    """Per-stage request totals of a pipeline run: the requests that
+    arrived (and of them, those routed in from upstream), and the shares
+    completed, completed late and dropped."""
+    lines = [f"{'stage':8s}{'arrived':>10s}{'routed':>10s}{'completed':>11s}"
+             f"{'late':>8s}{'dropped':>9s}"]
+    routed = np.asarray(history["routed"]).sum(axis=0)
+    for s in range(pipe.stages):
+        r = pipe.rows(s)
+        tot = {k: int(np.asarray(summ[k])[r].sum())
+               for k in ("arrived", "completed", "effective", "dropped")}
+        n = max(tot["arrived"], 1)
+        lines.append(
+            f"{s:<8d}{tot['arrived']:10d}{int(routed[r].sum()):10d}"
+            f"{tot['completed'] / n:11.3f}"
+            f"{(tot['completed'] - tot['effective']) / n:8.3f}"
+            f"{tot['dropped'] / n:9.3f}")
+    return "\n".join(lines)
 
 
 def _fluid_eval(cfg, fleet, traces):

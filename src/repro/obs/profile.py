@@ -186,6 +186,19 @@ def _kernel_args(name: str):
         caps = jnp.broadcast_to(
             jnp.asarray([2.5, 3.0, 4.0, 2.0, 8.0, 5.0], f32), (a, 6))
         return (*state, arrivals, caps), {}
+    if name == "pipeline_advance":
+        from repro.sim.state import SimParams, sim_init
+        sp = SimParams()
+        cams, stages = 2, 2
+        a = cams * stages
+        state = jax.vmap(lambda _: sim_init(sp))(jnp.arange(a))
+        k1 = jax.random.fold_in(key, 1)
+        arrivals = jax.random.randint(k1, (a, sp.k_ticks), 0, 7)
+        caps = jnp.broadcast_to(
+            jnp.asarray([2.5, 3.0, 4.0, 2.0, 8.0, 5.0], f32), (a, 6))
+        objects = jnp.full((a,), 2.5, f32)
+        phase = jnp.zeros((a,), f32)
+        return (*state, arrivals, caps, objects, phase), dict(stages=stages)
     raise KeyError(name)
 
 

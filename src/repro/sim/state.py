@@ -6,9 +6,15 @@ monotone stage counters, token-bucket service credits, and request-grade
 accumulators defined in ``repro.kernels.ref`` (the shared microtick math).
 Stage membership is positional — each pipeline stage's occupants are a
 contiguous ring segment between two counters — so queue lengths are counter
-differences, a request's deadline is ``arrive + slo_ticks``, and sizes are
-uniformly one object per request (the accumulators are the hook if
-objects-per-frame weighting is ever needed).
+differences and a request's deadline is ``arrive + slo_ticks``. A request
+is one unit of its model's work: a frame for a detector, one object crop
+for a model downstream of it.
+
+A ``Pipeline`` chains models: ``cameras`` feed-forward chains of ``stages``
+agents each, every agent its own ``SimState``. Stage 0 admits the camera's
+frames (``spread_arrivals`` of the trace rate); a later stage admits what
+its upstream agent completed, fanned out by the objects per frame
+(``fanout_arrivals``). See docs/architecture.md, "Pipelines".
 
 ``action_caps`` decodes an iAgent action (RES, BS, MT) into the per-tick
 service capacities of the twin with the SAME formulas as the fluid
@@ -21,12 +27,38 @@ import warnings
 from dataclasses import dataclass
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.fcpo import FCPOConfig
-from repro.core.env import EnvParams
+from repro.core.env import EnvParams, default_env_params
 from repro.kernels import ref as kref
+
+
+@dataclass(frozen=True)
+class Pipeline:
+    """Static layout of a fleet of feed-forward model chains (hashable — a
+    jit static argument): ``cameras`` chains of ``stages`` models, one
+    iAgent per model. Agents are stage-major: agent ``s * cameras + c``
+    runs stage ``s`` of camera ``c``, so each stage is one contiguous
+    slice of the agent axis. ``Pipeline(a)`` is a fleet of ``a`` single
+    models."""
+    cameras: int
+    stages: int = 1
+
+    def __post_init__(self):
+        if self.cameras < 1 or self.stages < 1:
+            raise ValueError(f"a pipeline needs >= 1 camera and stage, got "
+                             f"{self.cameras} x {self.stages}")
+
+    @property
+    def agents(self) -> int:
+        return self.cameras * self.stages
+
+    def rows(self, stage: int) -> slice:
+        """The agents of ``stage``."""
+        return slice(stage * self.cameras, (stage + 1) * self.cameras)
 
 
 @dataclass(frozen=True)
@@ -174,3 +206,48 @@ def spread_arrivals(sp: SimParams, rate, phase=0.0):
     counts = (cum[1:] - cum[:-1]).astype(jnp.int32)
     end = phase + rate * sp.dt * sp.k_ticks
     return counts, end - jnp.floor(end)
+
+
+def fanout_arrivals(objects, completions, phase):
+    """Per-tick arrivals of a pipeline stage from its upstream agent's
+    per-tick completions (late ones included: a late frame's objects are
+    still processed).
+
+    objects: (...) float32 — requests each upstream completion sends on
+    (the camera's objects per frame for the stage after the detector);
+    completions: (..., K) int32; phase: (...) float32 in [0, 1), the
+    fraction of a request carried from earlier intervals. With
+    cum(k) = phase + objects * sum_{j<=k} completions(j) and
+    cum(-1) = phase, the arrivals at tick k are
+    floor(cum(k)) - floor(cum(k-1)), and the new phase is
+    cum(K-1) - floor(cum(K-1)). Returns ((..., K) int32, new phase).
+
+    Arrivals enter the downstream ring in the tick of the completion that
+    sent them. Every quantity is exact in float32 when ``objects`` is a
+    multiple of 2**-8 and each interval's cum stays below 2**16, so every
+    backend then floors the same numbers."""
+    phase = jnp.asarray(phase, jnp.float32)
+    done = jnp.cumsum(completions, axis=-1).astype(jnp.float32)
+    cum = phase[..., None] + jnp.asarray(objects, jnp.float32)[..., None] \
+        * done
+    floors = jnp.floor(jnp.concatenate([phase[..., None], cum], axis=-1))
+    counts = (floors[..., 1:] - floors[..., :-1]).astype(jnp.int32)
+    end = cum[..., -1]
+    return counts, end - jnp.floor(end)
+
+
+def pipeline_env_params(pipe: Pipeline, speeds, stage_speed,
+                        stage_slo_s) -> EnvParams:
+    """Per-agent device profiles of a pipeline fleet, stage-major: agent
+    ``s * cameras + c`` runs at ``speeds[c] * stage_speed[s]`` (the
+    camera's device, scaled by how much lighter stage ``s``'s model is)
+    with the deadline ``stage_slo_s[s]``, counted from its own arrival."""
+    if len(stage_speed) != pipe.stages or len(stage_slo_s) != pipe.stages:
+        raise ValueError(f"need one speed factor and one SLO per stage "
+                         f"({pipe.stages}), got {len(stage_speed)} and "
+                         f"{len(stage_slo_s)}")
+    speeds = jnp.asarray(speeds, jnp.float32)
+    agent_speed = jnp.concatenate([speeds * f for f in stage_speed])
+    agent_slo = jnp.repeat(jnp.asarray(stage_slo_s, jnp.float32),
+                           pipe.cameras)
+    return jax.vmap(default_env_params)(agent_speed, agent_slo)

@@ -62,3 +62,13 @@ def sim_interval_recorded(state: SimState, arrivals: jnp.ndarray,
 
     carry, ticks = jax.lax.scan(tick, tuple(state), arrivals)
     return SimState(*carry), ticks
+
+
+def tick_completions(state: SimState, ticks: jnp.ndarray) -> jnp.ndarray:
+    """(A, K) completions in each microtick, from the (A, K, SIM_NCOUNTERS)
+    counter series ``sim_interval_recorded`` returns for an interval that
+    started from ``state``."""
+    completed = ticks[..., kref.SIM_COMPLETED]
+    before = jnp.concatenate([state.completed[:, None], completed[:, :-1]],
+                             axis=1)
+    return completed - before

@@ -185,9 +185,9 @@ class TestFidelityEnvelope:
                                2 * CFG.n_steps)
         _, hist = train_fleet(CFG, fleet, traces, learn=False,
                               federated=False)
-        _, _, summ = simulate_fleet(CFG, sp, fleet.astate.params, fleet.masks,
-                                    fleet.env_params, traces,
-                                    jax.random.PRNGKey(3))
+        summ = simulate_fleet(CFG, sp, fleet.astate.params, fleet.masks,
+                              fleet.env_params, traces,
+                              jax.random.PRNGKey(3)).summary
         thr_fluid = float(np.mean(hist["throughput"]))
         thr_twin = float(np.asarray(summ["throughput"]).mean())
         gap = abs(thr_fluid - thr_twin) / max(abs(thr_fluid), 1e-9)

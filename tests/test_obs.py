@@ -45,6 +45,7 @@ from repro.kernels.ref import (CAP_BATCH, CAP_POST, CAP_PRE, CAP_QCAP,
                                CAP_SLO, CAP_TBATCH)
 from repro.launch import watch
 from repro.obs import Tracer, validate_chrome_trace
+from repro.obs.profile import profile_kernels
 from repro.obs.requests import SEGMENTS, attribute_agent, attribute_run, \
     conservation_report, records_to_chrome, stage_decomposition
 from repro.sim import SimParams, make_scenario, simulate_fleet
@@ -157,6 +158,36 @@ class TestProfilerSpans:
         assert kernel_ops.KERNEL_JITS[kernel].__name__ == f"_{kernel}_impl"
         assert f"jit(_{kernel}_impl)" in lowered_scans("twin")
 
+    def test_sim_host_spans_in_order_once_per_call(self, tmp_path):
+        """``simulate_fleet``'s host stages, read by the twin-evaluation
+        cell's ``eval_*_ms`` readers, land once per call and in order."""
+        cfg, sp, a = FCPOConfig(), SimParams(), 2
+        fleet = fleet_init(cfg, a, jax.random.PRNGKey(SEED))
+        traces = make_scenario("steady", jax.random.PRNGKey(SEED + 2), a, 4)
+        args = (cfg, sp, fleet.astate.params, fleet.masks, fleet.env_params,
+                traces)
+        run = simulate_fleet(*args, jax.random.PRNGKey(SEED))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            simulate_fleet(*args, None, carry=run.carry)
+            simulate_fleet(*args, None, carry=run.carry)
+        names = ("sim.prep", "sim.call", "sim.fetch", "sim.summary")
+        spans = sorted((e for e in _profile_events(str(tmp_path))
+                        if e["name"] in names), key=lambda e: e["ts"])
+        assert [e["name"] for e in spans] == list(names) * 2
+        for prev, nxt in zip(spans, spans[1:]):
+            assert prev["ts"] + prev["dur"] <= nxt["ts"]
+
+    @pytest.mark.parametrize("kernel", sorted(kernel_ops.KERNEL_JITS))
+    def test_profile_kernels_covers_every_kernel_jit(self, kernel):
+        """``profile_kernels()`` walks all of ``KERNEL_JITS`` by default
+        (``benchmarks/fig_profile.py``'s gate): every kernel needs its
+        canonical workload there and must compile at it."""
+        stats = profile_kernels([kernel])
+        assert list(stats) == [kernel]
+        assert stats[kernel]["peak_bytes"] > 0
+
     def test_launcher_trace_out_writes_profiler_trace(self, tmp_path):
         out = tmp_path / "prof"
         env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -220,8 +251,8 @@ def recorded_run():
     traces = make_scenario("steady", jax.random.PRNGKey(SEED + 2), a, t)
     args = (cfg, sp, fleet.astate.params, fleet.masks, fleet.env_params,
             traces, jax.random.PRNGKey(SEED + 3))
-    state_plain, _, summ_plain = simulate_fleet(*args)
-    state, history, summ = simulate_fleet(*args, record_ticks=True)
+    state_plain, _, summ_plain, _ = simulate_fleet(*args)
+    state, history, summ, _ = simulate_fleet(*args, record_ticks=True)
     return {"sp": sp, "state_plain": state_plain, "state": state,
             "history": history, "summ": summ, "summ_plain": summ_plain}
 

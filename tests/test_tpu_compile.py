@@ -112,6 +112,23 @@ def test_queue_advance_vmap_is_one_call_over_agent_blocks(one_chip,
     assert txt.count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("cams, stages", [(40, 2), (400, 2), (40, 3)])
+def test_pipeline_advance_compiles(one_chip, compiled_kernels, pallas_grids,
+                                   cams, stages):
+    """A pipeline fleet's interval: one Mosaic call over blocks of cameras,
+    each block holding its cameras' every stage (one block for the
+    40-camera cell; 400 cameras run as padded blocks)."""
+    a = cams * stages
+    avals = _twin_avals(one_chip, a) + shapes(one_chip, ((a,), F32),
+                                              ((a,), F32))
+    fn = lambda *x: kops.pipeline_advance(*x, stages=stages)
+    blk = qa_mod.agent_block(cams, RING, HIST, K + 3, rows_per_agent=stages)
+    assert pallas_grids(fn, *avals) == [(-(-cams // blk),)]
+    assert (cams == 40) == (blk == cams)
+    txt = compiled_text(fn, *avals)
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("codec", kref.DELTA_CODECS)
 def test_delta_codec_compiles(one_chip, codec):
     avals = shapes(one_chip, ((A, PARAMS_L), F32), ((A, PARAMS_L), F32))
